@@ -1,22 +1,19 @@
-"""Single compatibility seam for every version-sensitive JAX surface.
+"""Single seam for every JAX surface outside the stable core API.
 
-The repo targets JAX 0.4.37 through current.  Upstream has renamed or
-moved several APIs we depend on; the paper's predictability story
-(PAPER.md §III: one statically-known substrate, identical behaviour
-everywhere) forbids scattering per-version branches through kernels and
-launch code.  All drift is absorbed here:
+The repo targets exactly the installed JAX (0.9.0).  The paper's
+predictability story (PAPER.md §III: one statically-known substrate,
+identical behaviour everywhere) forbids scattering raw
+``jax.experimental`` and sharding-internals references through kernels
+and launch code, so they all live here:
 
-  * Pallas TPU compiler params: ``TPUCompilerParams`` (<= 0.4.x) was
-    renamed ``CompilerParams`` (>= 0.5) -> ``tpu_compiler_params()``.
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(axis_types=...)``
-    (>= 0.5 only) -> ``AxisType`` fallback enum + ``make_mesh()``.
-  * ``Compiled.cost_analysis()`` returns a list of per-computation
-    dicts on 0.4.x and a flat dict on >= 0.5 ->
-    ``cost_analysis()`` / ``normalize_cost_analysis()``.
-  * ``shard_map`` lives at ``jax.shard_map`` (new) or
-    ``jax.experimental.shard_map`` (old) and renamed its replication
-    check ``check_rep`` -> ``check_vma`` -> ``shard_map()``.
-  * Pallas interpret-mode selection off-TPU -> ``resolve_interpret()``.
+  * Pallas TPU compiler params -> ``tpu_compiler_params()``.
+  * ``jax.sharding.AxisType`` / ``jax.make_mesh(axis_types=...)`` ->
+    ``AxisType`` + ``make_mesh()``.
+  * ``Compiled.cost_analysis()`` (a flat dict, or ``None`` on some
+    backends) -> ``cost_analysis()`` / ``normalize_cost_analysis()``.
+  * ``jax.shard_map`` with ``check_vma``/``axis_names`` -> ``shard_map()``.
+  * Pallas interpret-mode selection on the CPU -> ``resolve_interpret()``.
+  * Described TPU topologies (compile without a chip) -> ``tpu_topology()``.
 
 Policy (enforced by scripts/check_compat_imports.py, run as a tier-1
 test): no module outside this file may reference the raw symbols
@@ -24,139 +21,77 @@ directly.
 """
 from __future__ import annotations
 
-import enum
-import functools
-import inspect
+import dataclasses
 from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
 import jax
 
 __all__ = [
-    "JAX_VERSION",
-    "jax_version_at_least",
     "tpu_compiler_params",
     "AxisType",
     "auto_axis_types",
     "make_mesh",
     "cost_analysis",
     "normalize_cost_analysis",
-    "on_tpu",
     "resolve_interpret",
     "shard_map",
     "donated_jit",
     "aot_compile",
+    "tpu_topology",
 ]
-
-
-def _parse_version(v: str) -> Tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _parse_version(jax.__version__)
-
-
-def jax_version_at_least(*version: int) -> bool:
-    return JAX_VERSION >= tuple(version)
 
 
 # --------------------------------------------------- Pallas TPU params
 
-def _pltpu():
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu
-
-
-def _resolve_tpu_compiler_params_cls(mod=None):
-    """New layout first (>= 0.5), then the 0.4.x name.  ``mod`` is
-    injectable for unit tests."""
-    mod = mod if mod is not None else _pltpu()
-    for name in ("Compiler" "Params", "TPUCompiler" "Params"):
-        cls = getattr(mod, name, None)
-        if cls is not None:
-            return cls
-    raise AttributeError(
-        "jax.experimental.pallas.tpu exposes no compiler-params class "
-        f"(jax {jax.__version__}); update repro.compat")
-
-
 def tpu_compiler_params(*, dimension_semantics: Optional[Sequence[str]]
                         = None, **kwargs) -> Any:
-    """Construct Pallas TPU compiler params under any supported JAX.
+    """Construct Pallas TPU compiler params.
 
-    Unknown fields are dropped (not an error): a field the installed
-    JAX doesn't know is a hint it cannot honour, never a hard failure.
+    Unknown fields are dropped (not an error): a field this JAX doesn't
+    know is a hint it cannot honour, never a hard failure.
     """
-    cls = _resolve_tpu_compiler_params_cls()
+    from jax.experimental.pallas import tpu as pltpu
+    cls = pltpu.CompilerParams
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    try:
-        accepted = set(inspect.signature(cls).parameters)
-    except (TypeError, ValueError):  # pragma: no cover
-        accepted = set(kwargs)
+    accepted = {f.name for f in dataclasses.fields(cls)}
     return cls(**{k: v for k, v in kwargs.items() if k in accepted})
 
 
 # ------------------------------------------------------ mesh / AxisType
 
-class _FallbackAxisType(enum.Enum):
-    """Stand-in for the >= 0.5 axis-type enum on older JAX.  The values
-    only matter as distinct markers; pre-0.5 meshes are implicitly all
-    ``Auto`` so ``make_mesh`` simply drops them."""
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = getattr(jax.sharding, "Axis" "Type", _FallbackAxisType)
+AxisType = jax.sharding.AxisType
 
 
 def auto_axis_types(n: int) -> Tuple[Any, ...]:
-    """``(AxisType.Auto,) * n`` under whichever enum is in force."""
+    """``(AxisType.Auto,) * n``."""
     return (AxisType.Auto,) * n
 
 
-@functools.lru_cache(maxsize=1)
-def _make_mesh_params() -> frozenset:
-    return frozenset(inspect.signature(jax.make_mesh).parameters)
-
-
-def _mesh_kwargs(supported: frozenset, axis_types, devices) -> Dict:
+def _mesh_kwargs(axis_types, devices) -> Dict:
+    """Only the requested fields: an unset ``axis_types`` keeps
+    ``jax.make_mesh``'s own default."""
     kw: Dict[str, Any] = {}
     if devices is not None:
         kw["devices"] = devices
-    if axis_types is not None and "axis_types" in supported:
+    if axis_types is not None:
         kw["axis_types"] = axis_types
     return kw
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates pre-0.5 signatures: on JAX
-    without ``axis_types`` the request is dropped (old meshes behave as
-    all-Auto, which is exactly what dropping yields)."""
-    kw = _mesh_kwargs(_make_mesh_params(), axis_types, devices)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    """``jax.make_mesh`` passing only the fields the caller set."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         **_mesh_kwargs(axis_types, devices))
 
 
 # -------------------------------------------------------- cost analysis
 
 def normalize_cost_analysis(raw) -> Dict[str, float]:
-    """Flatten ``Compiled.cost_analysis()`` output to one str->float
-    dict regardless of JAX version.
-
-    0.4.x returns ``[{...}]`` (one record per computation; the first is
-    the main program), >= 0.5 returns the dict itself, and some
-    backends return ``None``.
-    """
-    if raw is None:
-        return {}
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
-    if not isinstance(raw, Mapping):  # pragma: no cover - defensive
+    """``Compiled.cost_analysis()`` as one str->float dict: numeric
+    entries only, and ``{}`` where the backend reports nothing."""
+    if not isinstance(raw, Mapping):
         return {}
     out = {}
     for k, v in raw.items():
@@ -176,8 +111,8 @@ def cost_analysis(compiled) -> Dict[str, float]:
 
 def donated_jit(fn, *, donate_argnums: Tuple[int, ...] = (),
                 static_argnums: Tuple[int, ...] = ()):
-    """``jax.jit`` with buffer donation, degrading gracefully where the
-    backend cannot honour it.
+    """``jax.jit`` with buffer donation, requested only where the
+    backend honours it.
 
     Donation is the serving steady state's realloc killer (the KV cache
     is updated in place instead of copied every decode step), but CPU —
@@ -197,66 +132,55 @@ def donated_jit(fn, *, donate_argnums: Tuple[int, ...] = (),
 def aot_compile(jitted, *args, **kwargs):
     """Ahead-of-time compile a jitted callable for example arguments.
 
-    ``jit(...).lower(...).compile()`` is the stable AOT spelling across
-    the supported span (jax.stages); wrapping it here keeps launch code
-    off the raw surface and gives one place to absorb future drift.
     The returned executable runs with ZERO compile-time jitter — the
     serving loop compiles before its timed region starts.
     """
     return jitted.lower(*args, **kwargs).compile()
 
 
+def tpu_topology(name: str):
+    """A described (not attached) TPU topology, e.g. ``"v5e:2x2"``: its
+    ``devices`` take shardings, so programs compile for that chip on a
+    host without one.  Raises where the TPU compiler is not installed."""
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu", topology_name=name)
+
+
 # ---------------------------------------------------- interpret select
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     """Kernel entry points take ``interpret=None`` = auto: compile on
-    TPU, interpret everywhere else (CPU validation path)."""
-    if interpret is None:
-        return not on_tpu()
-    return bool(interpret)
+    TPU, interpret on the CPU validation backend.  Any other backend
+    raises: a Pallas TPU kernel has no silent fallback there."""
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on backend {backend!r}; pass "
+        "interpret=True explicitly to interpret them there")
 
 
 # ----------------------------------------------------------- shard_map
 
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-def _shard_map_kwargs(params: frozenset, *, check: bool,
-                      auto: frozenset, axis_names: Sequence[str]) -> Dict:
-    """Map our stable options onto whichever spelling the resolved
-    shard_map uses (pure; unit-tested against both layouts)."""
-    kw: Dict[str, Any] = {}
-    if "check_vma" in params:
-        kw["check_vma"] = check
-    elif "check_rep" in params:
-        kw["check_rep"] = check
+def _shard_map_kwargs(*, check: bool, auto: frozenset,
+                      axis_names: Sequence[str]) -> Dict:
+    """``auto`` (the mesh axes left to GSPMD) becomes shard_map's
+    complementary ``axis_names`` (the manual axes)."""
+    kw: Dict[str, Any] = {"check_vma": check}
     if auto:
-        if "auto" in params:
-            kw["auto"] = auto
-        elif "axis_names" in params:
-            kw["axis_names"] = set(axis_names) - set(auto)
+        kw["axis_names"] = set(axis_names) - set(auto)
     return kw
 
 
 def shard_map(f, mesh, in_specs, out_specs, *, check: bool = False,
               auto: frozenset = frozenset()):
-    """Version-stable shard_map.
-
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old);
-    ``auto`` is the set of mesh axes left to GSPMD, translated to the
-    new API's complementary ``axis_names`` when needed.
-    """
-    fn = _resolve_shard_map()
-    params = frozenset(inspect.signature(fn).parameters)
-    kw = _shard_map_kwargs(params, check=check, auto=auto,
+    """``jax.shard_map`` with ``check`` -> ``check_vma`` and ``auto``
+    translated to ``axis_names``."""
+    kw = _shard_map_kwargs(check=check, auto=auto,
                            axis_names=mesh.axis_names)
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

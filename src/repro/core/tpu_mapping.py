@@ -35,7 +35,30 @@ class TPUChip:
     worst_mxu_eff: float = 0.85
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4 links.
 V5E = TPUChip()
+
+# Peak tables keyed by ``jax.Device.device_kind``.  A TPU whose kind is
+# missing here is an error, never a silent v5e.
+CHIPS = {"TPU v5 lite": V5E}
+
+
+def chip_for(device) -> TPUChip:
+    """The peak table a WCET bound is priced against on ``device``.
+
+    On a TPU the entry for its ``device_kind`` (unknown kinds raise);
+    on any other backend the bound stays the v5e *target*, as the CPU
+    validation runs print it."""
+    if device.platform != "tpu":
+        return V5E
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table for TPU kind {device.device_kind!r}; add it "
+            f"to repro.core.tpu_mapping.CHIPS (have {sorted(CHIPS)})"
+        ) from None
 
 
 def tpu_matmul_schedule(m: int, k: int, n: int, *, n_devices: int = 1,

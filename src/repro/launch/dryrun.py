@@ -3,9 +3,11 @@ cell on the production meshes and record memory / cost / collective
 analysis.  This is the proof that the distribution config is coherent
 without real hardware (see DESIGN.md and EXPERIMENTS.md §Dry-run).
 
-NOTE: the first two statements below must run before ANY other import —
-jax locks the device count on first init, and the dry-run needs 512
-placeholder host devices.  Do not set this flag globally.
+NOTE: the environment lines below must run before ANY other import —
+jax locks the platform and device count on first init, and the dry-run
+needs 512 placeholder CPU host devices.  Pinning the CPU keeps this
+tool off an attached TPU (one process per chip; a 16x16 mesh cannot be
+built from one chip).  Do not set these globally.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k \
@@ -13,7 +15,10 @@ Usage:
   python -m repro.launch.dryrun --all [--multi-pod both]   # orchestrator
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

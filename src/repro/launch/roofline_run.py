@@ -2,14 +2,19 @@
 single-pod production mesh, compose totals (piece x multiplier), add
 the analytic MODEL_FLOPS, and emit the three roofline terms.
 
-First two statements must precede any other import (jax device count).
+The environment lines must precede any other import: jax locks the
+platform and device count on first init, and this CPU-only tool must
+never take an attached TPU.
 
 Usage:
   python -m repro.launch.roofline_run --arch qwen2-72b --shape train_4k
   python -m repro.launch.roofline_run --all
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

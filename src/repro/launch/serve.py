@@ -25,6 +25,11 @@ and overruns walk the resilience ladder — record, then warn, then shed
 of queueing unboundedly (resilience.DeadlineMonitor; summary printed
 next to the jitter stats).
 
+On a TPU the bound is priced against that chip's entry in
+``core.tpu_mapping.CHIPS`` (an unknown ``device_kind`` raises); on the
+CPU it stays the v5e target.  ``run(parse_args([...]))`` is the same
+path for scripts (``chip_smoke.py``): it returns what ``main`` prints.
+
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
       --batch 4 --prompt-len 64 --gen 32
 
@@ -46,6 +51,9 @@ import numpy as np
 
 from repro import compat
 from repro.configs import get_config
+from repro.core.tpu_mapping import (V5E, TPUChip, chip_for,
+                                    serve_step_schedule, tpu_wcet)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import reduced_config
 from repro.models import lm as lm_mod
 from repro.models.lm import RunOptions
@@ -75,20 +83,22 @@ def shed_batch(cfg, cache, tok, n_new: int, cache_len: int,
     return jax.tree.map(shed, spec, cache), tok[:n_new]
 
 
-def plan_wcet_s(cfg, plan: dict, batch: int, n_params: int) -> float:
+def plan_wcet_s(cfg, plan: dict, batch: int, n_params: int,
+                chip: TPUChip = V5E) -> float:
     """The per-step WCET bound for the decode weight pass under the
     served plan's tile pins — the single source for both the printed
     bound and the derived deadline (tested: changing the plan's pins
     must change this number)."""
-    from repro.core.tpu_mapping import serve_step_schedule, tpu_wcet
-    sched = serve_step_schedule(batch, cfg.d_model, n_params, plan=plan)
-    return tpu_wcet(sched)
+    sched = serve_step_schedule(batch, cfg.d_model, n_params, plan=plan,
+                                chip=chip)
+    return tpu_wcet(sched, chip)
 
 
 def compile_step_fns(cfg, params, batch, opts: RunOptions,
                      prompt_len: int):
     """AOT-compile prefill and the donated-cache decode step for the
-    shapes in ``batch``; returns ``(prefill_c, step_c)`` ready to call.
+    shapes in ``batch``; returns ``(prefill_c, step_c, compile_s)``
+    with the two executables ready to call and their compile seconds.
 
     ``aot_compile`` populates nothing implicit — the returned compiled
     objects themselves must be called — which is exactly what keeps
@@ -97,15 +107,19 @@ def compile_step_fns(cfg, params, batch, opts: RunOptions,
     step_j = compat.donated_jit(
         lambda p, c, t, i: lm_mod.decode_step(cfg, p, c, t, i, opts),
         donate_argnums=(1,))
+    t0 = time.monotonic()
     prefill_c = compat.aot_compile(prefill_j, params, batch)
+    t1 = time.monotonic()
     logits0, cache0 = prefill_c(params, batch)
     tok0 = jnp.argmax(logits0[:, :cfg.vocab_size], axis=-1)
+    t2 = time.monotonic()
     step_c = compat.aot_compile(step_j, params, cache0, tok0,
                                 jnp.int32(prompt_len))
-    return prefill_c, step_c
+    t3 = time.monotonic()
+    return prefill_c, step_c, {"prefill": t1 - t0, "decode": t3 - t2}
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--batch", type=int, default=4)
@@ -128,13 +142,28 @@ def main():
                     help="deadline = WCET bound x slack (the bound "
                          "targets the TPU mapping; on other backends "
                          "the slack absorbs the platform gap)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> dict:
+    """Serve one batch: prefill the prompts, then ``args.gen`` decode
+    steps under the deadline ladder.
+
+    Returns the model and plan it served (``cfg``, ``params``,
+    ``plan``, ``plan_source``), the inputs and outputs (``prompt``
+    [B, P]; ``first_token`` [B], the prefill's greedy token;
+    ``generated``, one [B'] token array per decode step, B' < B after a
+    shed; ``prefill_logits`` and the last step's ``logits``), the
+    timings (``compile_s``, ``prefill_s``, ``step_s``), the WCET bound
+    ``wcet_s`` with the ``chip`` it was priced on, the deadline
+    monitor's ``deadline`` summary and the trace recorder ``trace``
+    (None unless ``REPRO_TRACE`` is set)."""
     cfg = get_config(args.arch)
     if not args.full:
         cfg = reduced_config(cfg, args)
     B, P, G = args.batch, args.prompt_len, args.gen
     total = P + G
+    chip = chip_for(jax.devices()[0])
 
     # serving plan: explicit flags > tuned cache entry > defaults
     problem = ModelProblem(
@@ -156,9 +185,8 @@ def main():
     if cfg.family == "encdec":
         batch["frames"] = jax.random.normal(key, (B, P, cfg.d_model))
 
-    trace_path = os.environ.get("REPRO_TRACE")
     rec = None
-    if trace_path:
+    if os.environ.get("REPRO_TRACE"):
         from repro.obs import TraceRecorder
         rec = TraceRecorder(time_unit="us")
 
@@ -166,13 +194,14 @@ def main():
     # the SAME plan the steps will execute, computed up front so it can
     # serve as the step deadline
     n_p = lm_mod.param_count(cfg)
-    wcet_s = plan_wcet_s(cfg, plan, B, n_p)
+    wcet_s = plan_wcet_s(cfg, plan, B, n_p, chip)
     deadline_s = (args.deadline_ms / 1e3 if args.deadline_ms > 0
                   else wcet_s * args.deadline_slack)
     dmon = DeadlineMonitor(deadline_s=deadline_s, trace=rec)
 
     # all compilation happens here, before anything is timed
-    prefill_c, step_c = compile_step_fns(cfg, params, batch, opts, P)
+    prefill_c, step_c, compile_s = compile_step_fns(cfg, params, batch,
+                                                    opts, P)
 
     t0 = time.monotonic()
     logits, cache = jax.block_until_ready(prefill_c(params, batch))
@@ -181,10 +210,12 @@ def main():
         rec.add_span("prefill", "serve", t0 * 1e6,
                      (t0 + t_prefill) * 1e6, cat="serve",
                      batch=B, prompt_len=P)
+    prefill_logits = logits
 
     out = []
     times = []
     tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
+    first_token = np.asarray(tok)
     for i in range(G):
         t1 = time.monotonic()
         logits, cache = step_c(params, cache, tok, jnp.int32(P + i))
@@ -211,13 +242,28 @@ def main():
             # new batch shape = new program: re-AOT-compile outside the
             # per-step timing so the shed path stays compile-free too
             shed_batch_dict = {k: v[:n_new] for k, v in batch.items()}
-            _, step_c = compile_step_fns(cfg, params, shed_batch_dict,
-                                         opts, P)
+            _, step_c, _ = compile_step_fns(cfg, params, shed_batch_dict,
+                                            opts, P)
 
-    # AOT warm-up means step 0 is a real step: every sample counts
-    times = np.array(times)
-    print(f"serving plan [{plan_source}]: {plan_sig(plan)}")
-    print(f"prefill: {t_prefill*1e3:.1f} ms for {B}x{P} tokens")
+    return {"cfg": cfg, "params": params, "plan": plan,
+            "plan_source": plan_source, "prompt": np.asarray(tokens),
+            "first_token": first_token, "generated": out,
+            "prefill_logits": prefill_logits, "logits": logits,
+            "compile_s": compile_s, "prefill_s": t_prefill,
+            # AOT warm-up means step 0 is a real step: every sample
+            # counts
+            "step_s": np.array(times), "wcet_s": wcet_s, "chip": chip,
+            "deadline": dmon.summary(), "trace": rec}
+
+
+def main():
+    args = parse_args()
+    enable_compile_cache()
+    r = run(args)
+    B, P, plan, times, out = (args.batch, args.prompt_len, r["plan"],
+                              r["step_s"], r["generated"])
+    print(f"serving plan [{r['plan_source']}]: {plan_sig(plan)}")
+    print(f"prefill: {r['prefill_s']*1e3:.1f} ms for {B}x{P} tokens")
     print(f"decode:  median {np.median(times)*1e3:.2f} ms/step  "
           f"std {times.std()*1e3:.3f} ms  "
           f"jitter(max-min) {(times.max()-times.min())*1e3:.3f} ms")
@@ -230,16 +276,18 @@ def main():
 
     print(f"TPU-target WCET bound per step (weight pass, "
           f"plan tiles {plan['mm_bm']}x{plan['mm_bn']}): "
-          f"{wcet_s*1e3:.3f} ms")
-    s = dmon.summary()
+          f"{r['wcet_s']*1e3:.3f} ms")
+    s = r["deadline"]
     print(f"deadline: {s['deadline_s']*1e3:.3f} ms/step  "
           f"overruns {s['overruns']}/{len(times)}  "
           f"ladder record/warn/shed "
           f"{s['n_record']}/{s['n_warn']}/{s['n_shed']}  "
           f"worst overrun {s['worst_overrun_s']*1e3:.3f} ms")
 
+    rec = r["trace"]
     if rec is not None and rec.spans:
         from repro.obs import write_chrome_trace
+        trace_path = os.environ["REPRO_TRACE"]
         write_chrome_trace(rec, trace_path)
         print(f"trace: {len(rec.spans)} spans -> {trace_path}")
 

@@ -15,8 +15,12 @@ from __future__ import annotations
 import argparse
 import os
 
+import jax
+
 from repro.configs import TrainConfig, get_config, reduce_config
+from repro.core.tpu_mapping import chip_for, serve_step_schedule, tpu_wcet
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import RunOptions
 from repro.runtime.trainer import Trainer
 
@@ -50,6 +54,7 @@ def main():
                          "targets the TPU mapping; on other backends "
                          "the slack absorbs the platform gap)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:
@@ -72,16 +77,16 @@ def main():
     # pass over B*S tokens, tiled by the resolved kernel plan; the
     # forward+backward pass streams each weight ~3x (fwd, grad-wrt-
     # input, grad-wrt-weight), hence the 3x on the one-pass bound.
-    from repro.core.tpu_mapping import serve_step_schedule, tpu_wcet
     from repro.models.lm import param_count
     from repro.tuning.model import ModelProblem, kernel_pins
     prob = ModelProblem(args.arch, args.batch * args.seq, args.seq,
                         1, layers=0 if args.full else args.layers,
                         d_model=args.d_model, vocab=args.vocab)
+    chip = chip_for(jax.devices()[0])
     sched = serve_step_schedule(args.batch * args.seq, cfg.d_model,
                                 param_count(cfg),
-                                plan=kernel_pins(cfg, prob))
-    wcet_s = 3.0 * tpu_wcet(sched)
+                                plan=kernel_pins(cfg, prob), chip=chip)
+    wcet_s = 3.0 * tpu_wcet(sched, chip)
     deadline_s = (args.deadline_ms / 1e3 if args.deadline_ms > 0
                   else wcet_s * args.deadline_slack)
     from repro.resilience.deadline import DeadlineMonitor

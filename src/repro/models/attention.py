@@ -42,14 +42,14 @@ def attn_spec(d_model: int, a: AttentionConfig, dtype: str,
     # (divisibility fallback) — see sharding/rules.py.
     p = {
         "wq": Par((d_model, H, hd), ("embed", "heads", "head_dim"),
-                  init="scaled", dtype=dtype),
+                  init="scaled", dtype=dtype, fan_in=d_model),
         "wk": Par((d_model, KV, hd), ("embed", "kv_heads", "head_dim"),
-                  init="scaled", dtype=dtype),
+                  init="scaled", dtype=dtype, fan_in=d_model),
         "wv": Par((d_model, KV, hd), ("embed", "kv_heads", "head_dim"),
-                  init="scaled", dtype=dtype),
+                  init="scaled", dtype=dtype, fan_in=d_model),
         "wo": Par((H, hd, d_out or d_model), ("heads", "head_dim",
                                               "embed"),
-                  init="scaled", dtype=dtype),
+                  init="scaled", dtype=dtype, fan_in=H * hd),
     }
     if a.qkv_bias:
         p["bq"] = Par((H, hd), ("heads", None), init="zeros", dtype=dtype)

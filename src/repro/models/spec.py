@@ -26,6 +26,9 @@ class Par:
     init: str = "normal"      # normal | zeros | ones | scaled | decay
     scale: float = 0.02
     dtype: str = "bfloat16"
+    # contracted size for init="scaled"; 0 = shape[-2], the input dim
+    # of a [..., in, out] matrix.  Head-split projections set it.
+    fan_in: int = 0
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -63,7 +66,8 @@ def _init_leaf(p: Par, key) -> jax.Array:
             -0.5 - 2.0 * jax.random.uniform(key, p.shape), dt)
     scale = p.scale
     if p.init == "scaled":
-        fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
+        fan_in = p.fan_in or (p.shape[-2] if len(p.shape) >= 2
+                              else p.shape[-1])
         scale = 1.0 / np.sqrt(max(1, fan_in))
     return jnp.asarray(scale * jax.random.normal(key, p.shape, jnp.float32),
                        dt)

@@ -13,7 +13,9 @@ os.environ.setdefault("REPRO_AUTOTUNE", "0")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-jax.config.update("jax_platform_name", "cpu")
+# The suite runs on the CPU only, even where a TPU is attached: a test
+# process that took the chip would hold it for its whole life.
+jax.config.update("jax_platforms", "cpu")
 
 from repro.configs import get_config  # noqa: E402
 from repro.models.lm import RunOptions  # noqa: E402

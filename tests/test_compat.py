@@ -1,58 +1,12 @@
-"""Unit tests for the repro.compat version seam: each shim must resolve
-the right symbol under BOTH the old (jax 0.4.x) and new (jax >= 0.5)
-attribute layouts, exercised via synthetic module objects so the tests
-pass regardless of the installed JAX.
+"""Unit tests for the repro.compat seam against the installed JAX.
 
-Note: raw symbol names are built by concatenation — the compat-import
-lint (scripts/check_compat_imports.py) greps for the literal spellings.
+Note: raw symbol names never appear literally — the compat-import lint
+(scripts/check_compat_imports.py) greps for their spellings.
 """
-import types
-
-import pytest
-
 from repro import compat
-
-_OLD_CP = "TPUCompiler" + "Params"     # jax <= 0.4.x spelling
-_NEW_CP = "Compiler" + "Params"        # jax >= 0.5 spelling
 
 
 # ------------------------------------------------ compiler params class
-
-def _fake_pltpu(**attrs):
-    mod = types.SimpleNamespace()
-    for name, val in attrs.items():
-        setattr(mod, name, val)
-    return mod
-
-
-def test_resolves_old_compiler_params_layout():
-    class Old:
-        pass
-    mod = _fake_pltpu(**{_OLD_CP: Old})
-    assert compat._resolve_tpu_compiler_params_cls(mod) is Old
-
-
-def test_resolves_new_compiler_params_layout():
-    class New:
-        pass
-    mod = _fake_pltpu(**{_NEW_CP: New})
-    assert compat._resolve_tpu_compiler_params_cls(mod) is New
-
-
-def test_new_layout_wins_when_both_exist():
-    class Old:
-        pass
-
-    class New:
-        pass
-    mod = _fake_pltpu(**{_OLD_CP: Old, _NEW_CP: New})
-    assert compat._resolve_tpu_compiler_params_cls(mod) is New
-
-
-def test_missing_layout_raises():
-    with pytest.raises(AttributeError):
-        compat._resolve_tpu_compiler_params_cls(_fake_pltpu())
-
 
 def test_tpu_compiler_params_real_jax():
     p = compat.tpu_compiler_params(
@@ -74,18 +28,10 @@ def test_axis_type_has_auto():
     assert compat.auto_axis_types(3) == (compat.AxisType.Auto,) * 3
 
 
-def test_mesh_kwargs_old_signature_drops_axis_types():
-    old_sig = frozenset({"axis_shapes", "axis_names", "devices"})
-    kw = compat._mesh_kwargs(old_sig, compat.auto_axis_types(2), None)
-    assert kw == {}
-
-
 def test_mesh_kwargs_new_signature_passes_axis_types():
-    new_sig = frozenset({"axis_shapes", "axis_names", "devices",
-                         "axis_types"})
     types_ = compat.auto_axis_types(2)
-    kw = compat._mesh_kwargs(new_sig, types_, None)
-    assert kw == {"axis_types": types_}
+    assert compat._mesh_kwargs(types_, None) == {"axis_types": types_}
+    assert compat._mesh_kwargs(None, None) == {}
 
 
 def test_make_mesh_real_jax_single_device():
@@ -96,13 +42,6 @@ def test_make_mesh_real_jax_single_device():
 
 
 # -------------------------------------------------------- cost analysis
-
-def test_normalize_cost_analysis_old_list_shape():
-    raw = [{"flops": 10.0, "bytes accessed": 5.0, "utilization0{}": 1.0}]
-    ca = compat.normalize_cost_analysis(raw)
-    assert ca["flops"] == 10.0
-    assert ca["bytes accessed"] == 5.0
-
 
 def test_normalize_cost_analysis_new_dict_shape():
     ca = compat.normalize_cost_analysis({"flops": 7, "transcendentals": 1})
@@ -132,27 +71,23 @@ def test_resolve_interpret_explicit_passthrough():
 
 
 def test_resolve_interpret_auto_off_tpu(monkeypatch):
-    monkeypatch.setattr(compat, "on_tpu", lambda: False)
+    """Auto-select interprets on the CPU only: compile on a TPU, and
+    refuse any other backend instead of falling back."""
+    import pytest
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: "cpu")
     assert compat.resolve_interpret(None) is True
-    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: "tpu")
     assert compat.resolve_interpret(None) is False
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        compat.resolve_interpret(None)
+    assert compat.resolve_interpret(True) is True
 
 
 # ----------------------------------------------------------- shard_map
 
-def test_shard_map_kwargs_old_layout():
-    params = frozenset({"f", "mesh", "in_specs", "out_specs",
-                        "check_rep", "auto"})
-    kw = compat._shard_map_kwargs(params, check=False,
-                                  auto=frozenset({"data"}),
-                                  axis_names=("pod", "data"))
-    assert kw == {"check_rep": False, "auto": frozenset({"data"})}
-
-
 def test_shard_map_kwargs_new_layout():
-    params = frozenset({"f", "mesh", "in_specs", "out_specs",
-                        "check_vma", "axis_names"})
-    kw = compat._shard_map_kwargs(params, check=False,
+    kw = compat._shard_map_kwargs(check=False,
                                   auto=frozenset({"data"}),
                                   axis_names=("pod", "data"))
     assert kw == {"check_vma": False, "axis_names": {"pod"}}

@@ -32,6 +32,7 @@ def test_compressed_mean_multipod():
     """2-pod mean via the int8 wire format, on real host devices: pod 0
     holds g, pod 1 holds 3g -> compressed mean ~= 2g within the
     quantization bound."""
+    import os
     import subprocess
     import sys
     code = """
@@ -58,6 +59,7 @@ assert err <= float(jnp.max(jnp.abs(3 * g))) / 127.0 + 1e-6, err
 print("OK", err)
 """
     r = subprocess.run([sys.executable, "-c", code], cwd=".",
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

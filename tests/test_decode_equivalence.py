@@ -2,6 +2,8 @@
 reproduce the full-forward logits (teacher forcing) — validates cache
 semantics for every layer family (GQA, sliding-window, MoE, Mamba2
 conv+ssm state, RWKV6 shift+wkv state, enc-dec cross-attn)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,6 +22,12 @@ B, S, EXTRA = 2, 32, 6
 @pytest.mark.parametrize("arch", ARCHS)
 def test_decode_matches_full_forward(arch):
     cfg = tiny_cfg(arch, num_layers=TINY_LAYERS[arch], dtype="float32")
+    if cfg.moe:
+        # decode routes one token per group and never overflows an
+        # expert; the full forward must be dropless too, or the two
+        # differ by design (capacity drops), not by a cache bug
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, capacity_factor=cfg.moe.num_experts / cfg.moe.top_k))
     key = jax.random.PRNGKey(2)
     params = init_params(cfg, key)
     toks = jax.random.randint(key, (B, S + EXTRA), 0, cfg.vocab_size)
@@ -46,7 +54,6 @@ def test_windowed_ring_cache_matches_full(monkeypatch):
     """wincache variant: sliding-window layers keep an O(window) ring
     buffer; decode must still reproduce the full forward exactly
     (gemma3-style 5:1 local:global pattern)."""
-    import dataclasses
     from repro.configs import get_config
     from repro.models import compute_logits, forward_hidden, init_params
     cfg = get_config("gemma3-12b")

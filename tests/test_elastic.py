@@ -1,6 +1,7 @@
 """Elastic scaling: a checkpoint written under one mesh restores under
 a different device count (node-failure recovery path).  Subprocesses
 own their device counts (process-global in jax)."""
+import os
 import subprocess
 import sys
 
@@ -93,9 +94,11 @@ def test_straggler_monitor_unpaired_step_end():
 def test_checkpoint_survives_remesh(tmp_path):
     d = str(tmp_path)
     r1 = subprocess.run([sys.executable, "-c", _SAVE, d], cwd=".",
-                        capture_output=True, text=True, timeout=300)
+                        capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r1.returncode == 0, r1.stderr[-2000:]
     r2 = subprocess.run([sys.executable, "-c", _RESTORE, d], cwd=".",
-                        capture_output=True, text=True, timeout=300)
+                        capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert "RESTORED" in r2.stdout

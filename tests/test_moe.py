@@ -77,6 +77,7 @@ def test_ep_matches_einsum_single_device():
 def test_ep_multidevice():
     """EP correctness across real shards (8 host devices, 2x4 mesh) —
     runs in a subprocess because the device count is process-global."""
+    import os
     import subprocess
     import sys
     code = """
@@ -104,6 +105,7 @@ assert err < 2e-5, err
 print("OK", err)
 """
     r = subprocess.run([sys.executable, "-c", code], cwd=".",
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=300,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

@@ -49,3 +49,83 @@ def test_serving_is_time_predictable_by_construction():
     assert jnp.array_equal(l1, l2)
     for a, b in zip(jax.tree.leaves(c1), jax.tree.leaves(c2)):
         assert jnp.array_equal(a, b)
+
+
+def _serve_args(*extra):
+    from repro.launch import serve
+    return serve.parse_args(["--batch", "2", "--prompt-len", "16",
+                             "--layers", "2", "--d-model", "64",
+                             "--vocab", "256", *extra])
+
+
+def test_serve_run_returns_full_batch_result():
+    """``launch.serve.run`` is the scripted entry point: one batch served
+    whole (no deadline pressure) comes back with every field chip_smoke
+    checks."""
+    import numpy as np
+
+    from repro.core.tpu_mapping import V5E
+    from repro.launch import serve
+    r = serve.run(_serve_args("--gen", "3", "--deadline-ms", "1e6"))
+    assert r["prompt"].shape == (2, 16)
+    assert r["first_token"].shape == (2,)
+    assert [g.shape for g in r["generated"]] == [(2,)] * 3
+    assert r["step_s"].shape == (3,) and r["prefill_s"] > 0
+    assert set(r["compile_s"]) == {"prefill", "decode"}
+    assert r["plan_source"] == "defaults" and r["chip"] is V5E
+    assert r["wcet_s"] > 0 and r["deadline"]["n_shed"] == 0
+    v = r["cfg"].vocab_size
+    assert np.isfinite(np.asarray(r["logits"][:, :v])).all()
+    assert np.isfinite(np.asarray(r["prefill_logits"][:, :v])).all()
+
+
+def test_serve_run_reports_a_deadline_shed():
+    """A shed must be visible in the result (smaller rows after it, and
+    the monitor's count), so a smoke run can refuse it."""
+    from repro.launch import serve
+    r = serve.run(_serve_args("--gen", "5", "--deadline-ms", "1e-6"))
+    rows = [g.shape[0] for g in r["generated"]]
+    assert rows[0] == 2 and rows[-1] == 1, rows
+    assert r["deadline"]["n_shed"] >= 1
+
+
+def test_serve_run_refuses_unknown_tpu_kind(monkeypatch):
+    """On a TPU that the peak table lacks, serving raises before it
+    prices a bound against the wrong chip."""
+    import pytest
+    from types import SimpleNamespace
+
+    from repro.launch import serve
+    monkeypatch.setattr(serve.jax, "devices", lambda *a: [
+        SimpleNamespace(platform="tpu", device_kind="TPU v99")])
+    with pytest.raises(ValueError, match="TPU v99"):
+        serve.run(_serve_args("--gen", "2"))
+
+
+def test_compile_cache_follows_env(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and
+    the helper sets nothing."""
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_inside_checkout(monkeypatch):
+    """Otherwise the cache sits at one fixed, git-ignored path in the
+    repository (never a temp name, pid or time)."""
+    import pathlib
+
+    from repro.launch.compile_cache import enable_compile_cache
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == first
+        assert enable_compile_cache() == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert pathlib.Path(first) == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()

@@ -37,3 +37,26 @@ def test_wcet_scales_down_with_devices():
     # DMA is shared (the paper's serialized management DMA) but compute
     # parallelizes: 4 devices must be meaningfully faster
     assert four < one
+
+
+@pytest.mark.parametrize("platform,kind", [("tpu", "TPU v5 lite"),
+                                           ("cpu", "cpu")])
+def test_chip_table_resolves_known_devices(platform, kind):
+    """A listed TPU kind gets its own peaks; a CPU host prices the
+    bound against the v5e target, as the validation runs print it."""
+    from types import SimpleNamespace
+
+    from repro.core.tpu_mapping import CHIPS, chip_for
+    chip = chip_for(SimpleNamespace(platform=platform, device_kind=kind))
+    assert chip is V5E
+    assert CHIPS["TPU v5 lite"].peak_flops == 197e12
+    assert CHIPS["TPU v5 lite"].hbm_bw == 819e9
+
+
+def test_chip_table_unknown_tpu_kind_raises():
+    """A TPU missing from the table is an error, never a silent v5e."""
+    from types import SimpleNamespace
+
+    from repro.core.tpu_mapping import chip_for
+    with pytest.raises(ValueError, match="TPU v99"):
+        chip_for(SimpleNamespace(platform="tpu", device_kind="TPU v99"))
