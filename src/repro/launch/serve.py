@@ -33,15 +33,18 @@ path for scripts (``chip_smoke.py``): it returns what ``main`` prints.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
       --batch 4 --prompt-len 64 --gen 32
 
-Set ``REPRO_TRACE=/path/serve.json`` to record the prefill and every
-decode step as spans on the ``serve`` track (plus a per-step latency
-counter and the ``deadline_*`` instants) and dump a Chrome trace at
-exit — the same knob the trainer and the kernel-conformance harness
-honor.
+Set ``REPRO_TRACE=/path/dir`` to write a JAX profiler trace of the
+served batch there (``jax.profiler.trace``): the host's phases as
+annotations (``prefill``, ``decode_step``, ``sample_sync``), inside
+them the step programs' dispatches (``prefill_dispatch``,
+``decode_dispatch``), and on the device's clock the operations of
+``jit_prefill`` and ``jit_decode_step``, each named by its HLO
+instruction (``repro.obs.op_blocks`` maps those to the model's blocks).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 
@@ -94,29 +97,50 @@ def plan_wcet_s(cfg, plan: dict, batch: int, n_params: int,
     return tpu_wcet(sched, chip)
 
 
+class StepProgram:
+    """A compiled step program whose every call the host makes inside
+    the profiler annotation ``span``, so a profiler trace shows how long
+    the host takes to dispatch it; ``compiled`` is the executable (its
+    text maps the trace's operations to blocks: ``repro.obs.op_blocks``)."""
+
+    def __init__(self, compiled, span: str):
+        self.compiled, self.span = compiled, span
+
+    def __call__(self, *args):
+        with jax.profiler.TraceAnnotation(self.span):
+            return self.compiled(*args)
+
+
 def compile_step_fns(cfg, params, batch, opts: RunOptions,
                      prompt_len: int):
     """AOT-compile prefill and the donated-cache decode step for the
-    shapes in ``batch``; returns ``(prefill_c, step_c, compile_s)``
-    with the two executables ready to call and their compile seconds.
+    shapes in ``batch``; returns ``(prefill, step, compile_s)``: the two
+    ``StepProgram``s (modules ``jit_prefill`` and ``jit_decode_step``,
+    dispatched inside ``prefill_dispatch`` and ``decode_dispatch``)
+    ready to call, and their compile seconds.
 
     ``aot_compile`` populates nothing implicit — the returned compiled
     objects themselves must be called — which is exactly what keeps
     compilation out of the timed region (and off the jitter stats)."""
-    prefill_j = jax.jit(lambda p, b: lm_mod.prefill(cfg, p, b, opts))
-    step_j = compat.donated_jit(
-        lambda p, c, t, i: lm_mod.decode_step(cfg, p, c, t, i, opts),
-        donate_argnums=(1,))
+    def prefill(p, b):
+        return lm_mod.prefill(cfg, p, b, opts)
+
+    def decode_step(p, c, t, i):
+        return lm_mod.decode_step(cfg, p, c, t, i, opts)
+
     t0 = time.monotonic()
-    prefill_c = compat.aot_compile(prefill_j, params, batch)
+    prefill_c = compat.aot_compile(jax.jit(prefill), params, batch)
     t1 = time.monotonic()
     logits0, cache0 = prefill_c(params, batch)
     tok0 = jnp.argmax(logits0[:, :cfg.vocab_size], axis=-1)
     t2 = time.monotonic()
-    step_c = compat.aot_compile(step_j, params, cache0, tok0,
-                                jnp.int32(prompt_len))
+    step_c = compat.aot_compile(
+        compat.donated_jit(decode_step, donate_argnums=(1,)), params,
+        cache0, tok0, jnp.int32(prompt_len))
     t3 = time.monotonic()
-    return prefill_c, step_c, {"prefill": t1 - t0, "decode": t3 - t2}
+    return (StepProgram(prefill_c, "prefill_dispatch"),
+            StepProgram(step_c, "decode_dispatch"),
+            {"prefill": t1 - t0, "decode": t3 - t2})
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -155,9 +179,10 @@ def run(args: argparse.Namespace) -> dict:
     ``generated``, one [B'] token array per decode step, B' < B after a
     shed; ``prefill_logits`` and the last step's ``logits``), the
     timings (``compile_s``, ``prefill_s``, ``step_s``), the WCET bound
-    ``wcet_s`` with the ``chip`` it was priced on, the deadline
-    monitor's ``deadline`` summary and the trace recorder ``trace``
-    (None unless ``REPRO_TRACE`` is set)."""
+    ``wcet_s`` with the ``chip`` it was priced on, and the deadline
+    monitor's ``deadline`` summary.  With ``REPRO_TRACE`` set, the
+    prefill and the decode steps run under the JAX profiler, which
+    writes its trace into that directory."""
     cfg = get_config(args.arch)
     if not args.full:
         cfg = reduced_config(cfg, args)
@@ -185,11 +210,6 @@ def run(args: argparse.Namespace) -> dict:
     if cfg.family == "encdec":
         batch["frames"] = jax.random.normal(key, (B, P, cfg.d_model))
 
-    rec = None
-    if os.environ.get("REPRO_TRACE"):
-        from repro.obs import TraceRecorder
-        rec = TraceRecorder(time_unit="us")
-
     # static-schedule WCET bound for the decode weight pass, built from
     # the SAME plan the steps will execute, computed up front so it can
     # serve as the step deadline
@@ -197,37 +217,53 @@ def run(args: argparse.Namespace) -> dict:
     wcet_s = plan_wcet_s(cfg, plan, B, n_p, chip)
     deadline_s = (args.deadline_ms / 1e3 if args.deadline_ms > 0
                   else wcet_s * args.deadline_slack)
-    dmon = DeadlineMonitor(deadline_s=deadline_s, trace=rec)
+    dmon = DeadlineMonitor(deadline_s=deadline_s)
 
     # all compilation happens here, before anything is timed
     prefill_c, step_c, compile_s = compile_step_fns(cfg, params, batch,
                                                     opts, P)
 
+    trace_dir = os.environ.get("REPRO_TRACE")
+    with (jax.profiler.trace(trace_dir) if trace_dir
+          else contextlib.nullcontext()):
+        r = _serve_batch(cfg, args, params, batch, opts, prefill_c, step_c,
+                         dmon)
+    return {"cfg": cfg, "params": params, "plan": plan,
+            "plan_source": plan_source, "prompt": np.asarray(tokens),
+            "compile_s": compile_s, "wcet_s": wcet_s, "chip": chip,
+            "deadline": dmon.summary(), **r}
+
+
+def _serve_batch(cfg, args, params, batch, opts, prefill_c, step_c,
+                 dmon) -> dict:
+    """The timed part of ``run``: the prefill, then the decode steps
+    under the deadline ladder, each phase inside the profiler
+    annotation the benchmark's serving loop gives it (``prefill``,
+    ``decode_step``, ``sample_sync``)."""
+    annotate = jax.profiler.TraceAnnotation
+    P, G = args.prompt_len, args.gen
+    deadline_s = dmon.deadline_s
     t0 = time.monotonic()
-    logits, cache = jax.block_until_ready(prefill_c(params, batch))
+    with annotate("prefill"):
+        logits, cache = jax.block_until_ready(prefill_c(params, batch))
     t_prefill = time.monotonic() - t0
-    if rec is not None:
-        rec.add_span("prefill", "serve", t0 * 1e6,
-                     (t0 + t_prefill) * 1e6, cat="serve",
-                     batch=B, prompt_len=P)
     prefill_logits = logits
 
     out = []
     times = []
-    tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
-    first_token = np.asarray(tok)
+    with annotate("sample_sync"):
+        tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
+        first_token = np.asarray(tok)
     for i in range(G):
         t1 = time.monotonic()
-        logits, cache = step_c(params, cache, tok, jnp.int32(P + i))
-        logits = jax.block_until_ready(logits)
+        with annotate("decode_step"):
+            logits, cache = step_c(params, cache, tok, jnp.int32(P + i))
+            logits = jax.block_until_ready(logits)
         t2 = time.monotonic()
         times.append(t2 - t1)
-        if rec is not None:
-            rec.add_span(f"decode{i}", "serve", t1 * 1e6, t2 * 1e6,
-                         cat="serve", pos=P + i)
-            rec.counter("step_ms", (t2 - t1) * 1e3, track="serve")
-        tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
-        out.append(np.asarray(tok))
+        with annotate("sample_sync"):
+            tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
+            out.append(np.asarray(tok))
         action = dmon.observe(i, t2 - t1)
         if action == "warn":
             print(f"deadline overrun at decode step {i}: "
@@ -237,7 +273,7 @@ def run(args: argparse.Namespace) -> dict:
             n_new = tok.shape[0] // 2
             print(f"deadline ladder: shedding batch "
                   f"{tok.shape[0]} -> {n_new} at decode step {i}")
-            cache, tok = shed_batch(cfg, cache, tok, n_new, total,
+            cache, tok = shed_batch(cfg, cache, tok, n_new, P + G,
                                     opts.windowed_cache)
             # new batch shape = new program: re-AOT-compile outside the
             # per-step timing so the shed path stays compile-free too
@@ -245,15 +281,12 @@ def run(args: argparse.Namespace) -> dict:
             _, step_c, _ = compile_step_fns(cfg, params, shed_batch_dict,
                                             opts, P)
 
-    return {"cfg": cfg, "params": params, "plan": plan,
-            "plan_source": plan_source, "prompt": np.asarray(tokens),
-            "first_token": first_token, "generated": out,
+    return {"first_token": first_token, "generated": out,
             "prefill_logits": prefill_logits, "logits": logits,
-            "compile_s": compile_s, "prefill_s": t_prefill,
+            "prefill_s": t_prefill,
             # AOT warm-up means step 0 is a real step: every sample
             # counts
-            "step_s": np.array(times), "wcet_s": wcet_s, "chip": chip,
-            "deadline": dmon.summary(), "trace": rec}
+            "step_s": np.array(times)}
 
 
 def main():
@@ -283,13 +316,8 @@ def main():
           f"ladder record/warn/shed "
           f"{s['n_record']}/{s['n_warn']}/{s['n_shed']}  "
           f"worst overrun {s['worst_overrun_s']*1e3:.3f} ms")
-
-    rec = r["trace"]
-    if rec is not None and rec.spans:
-        from repro.obs import write_chrome_trace
-        trace_path = os.environ["REPRO_TRACE"]
-        write_chrome_trace(rec, trace_path)
-        print(f"trace: {len(rec.spans)} spans -> {trace_path}")
+    if os.environ.get("REPRO_TRACE"):
+        print(f"trace: profiler trace -> {os.environ['REPRO_TRACE']}")
 
 
 if __name__ == "__main__":

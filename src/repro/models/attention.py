@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import AttentionConfig
 from repro.models.common import apply_rope, rmsnorm, rmsnorm_spec
 from repro.models.spec import Par
+from repro.obs.blocks import ATTN_CORE, ATTN_PROJ
 
 NEG_INF = -1e30
 _BIG_WINDOW = 1 << 30
@@ -65,6 +66,7 @@ def attn_spec(d_model: int, a: AttentionConfig, dtype: str,
 # projections
 
 
+@jax.named_scope(ATTN_PROJ)
 def qkv_project(p: dict, x: jax.Array, a: AttentionConfig,
                 positions: jax.Array, theta) -> Tuple[jax.Array, jax.Array,
                                                       jax.Array]:
@@ -85,6 +87,7 @@ def qkv_project(p: dict, x: jax.Array, a: AttentionConfig,
     return q, k, v
 
 
+@jax.named_scope(ATTN_PROJ)
 def out_project(p: dict, o: jax.Array) -> jax.Array:
     return jnp.einsum("bsnh,nhd->bsd", o, p["wo"])
 
@@ -118,6 +121,7 @@ def _block_attn(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
     return o
 
 
+@jax.named_scope(ATTN_CORE)
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, pos_q: jax.Array,
          pos_k: jax.Array, *, causal: bool, window, scale: float,
          chunk_q: int = 0, chunk_kv: int = 0) -> jax.Array:
@@ -230,10 +234,11 @@ def decode_attention(p: dict, x: jax.Array, a: AttentionConfig,
     is_ring = window > 0 and L <= window if isinstance(window, int) \
         else False
     slot = pos_i % L if is_ring else pos_i
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, k_new.astype(cache_k.dtype), (zero, slot, zero, zero))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, v_new.astype(cache_v.dtype), (zero, slot, zero, zero))
+    with jax.named_scope(ATTN_CORE):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k_new.astype(cache_k.dtype), (zero, slot, zero, zero))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v_new.astype(cache_v.dtype), (zero, slot, zero, zero))
     s_idx = jnp.arange(L, dtype=jnp.int32)
     if is_ring:
         # newest position in each slot; slots "ahead" of pos wrap to

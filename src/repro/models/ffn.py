@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.models.common import activate, is_gated
 from repro.models.spec import Par
+from repro.obs.blocks import FFN
 
 
 # ---------------------------------------------------------------------------
@@ -40,6 +41,7 @@ def dense_ffn_spec(d_model: int, d_ff: int, activation: str,
     return p
 
 
+@jax.named_scope(FFN)
 def dense_ffn(p: dict, x: jax.Array, activation: str) -> jax.Array:
     hg = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
     hu = jnp.einsum("bsd,df->bsf", x, p["w_up"]) if "w_up" in p else None
@@ -241,6 +243,7 @@ def moe_ffn_ep(p: dict, x: jax.Array, m: MoEConfig, activation: str,
     return fn(x, p["router"], *ws)
 
 
+@jax.named_scope(FFN)
 def moe_ffn(p: dict, x: jax.Array, m: MoEConfig, activation: str,
             impl: str = "einsum", x_sharding=None
             ) -> Tuple[jax.Array, jax.Array]:

@@ -28,6 +28,7 @@ from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import rmsnorm, rmsnorm_spec
 from repro.models.spec import Par, init_tree, stack
+from repro.obs.blocks import ATTN_CORE, HEAD
 
 MAX_POS_TABLE = 32_768  # whisper learned-position tables
 
@@ -155,6 +156,7 @@ def _head_table(cfg: ModelConfig, params: dict) -> jax.Array:
     return params["embed"] if cfg.tie_embeddings else params["lm_head"]
 
 
+@jax.named_scope(HEAD)
 def compute_logits(cfg: ModelConfig, params: dict,
                    x: jax.Array) -> jax.Array:
     """x: [B, d] -> fp32 logits [B, padded_vocab] (padding masked)."""
@@ -201,6 +203,7 @@ def lm_loss(cfg: ModelConfig, params: dict, x: jax.Array,
 # full-sequence unit application (train / prefill)
 
 
+@jax.named_scope(ATTN_CORE)
 def _to_cache_buf(k: jax.Array, cache_len: int,
                   opts: RunOptions = DEFAULT_OPTS,
                   window: int = 0) -> jax.Array:
@@ -397,7 +400,8 @@ def forward_hidden(cfg: ModelConfig, params: dict, batch: dict,
             memory, shared, cache_len)
         aux = aux + a_i
         caches[f"stage{si}"] = c_i
-    x = rmsnorm(x, params["final_norm"])
+    with jax.named_scope(HEAD):
+        x = rmsnorm(x, params["final_norm"])
     return x, aux, (caches if collect else None)
 
 
@@ -534,6 +538,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
                 ncl.append(ci)
             nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncl)
         new_caches[f"stage{si}"] = nc
-    x = rmsnorm(x, params["final_norm"])
+    with jax.named_scope(HEAD):
+        x = rmsnorm(x, params["final_norm"])
     logits = compute_logits(cfg, params, x[:, 0])
     return logits, new_caches

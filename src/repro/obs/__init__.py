@@ -16,7 +16,12 @@ package makes it observable:
 - ``report``       — schema-versioned structured sink for
   ``benchmarks/run.py --json`` so the BENCH trajectory is machine-
   readable instead of print-only CSV.
+- ``blocks``       — the block names the model code scopes the served
+  step programs with, and the map from a compiled program's HLO
+  operations to them (what a device trace's operation time is summed
+  by).
 """
+from repro.obs.blocks import BLOCKS, op_blocks, op_names
 from repro.obs.chrome_trace import to_chrome_trace, write_chrome_trace
 from repro.obs.jitter import JitterStats, jitter_stats, simulate_sweep
 from repro.obs.report import (BENCH_SCHEMA_VERSION, hw_fingerprint,
@@ -25,6 +30,7 @@ from repro.obs.trace import Counter, Instant, Span, TraceRecorder
 
 __all__ = [
     "BENCH_SCHEMA_VERSION",
+    "BLOCKS",
     "Counter",
     "Instant",
     "JitterStats",
@@ -33,6 +39,8 @@ __all__ = [
     "hw_fingerprint",
     "jitter_stats",
     "make_report",
+    "op_blocks",
+    "op_names",
     "simulate_sweep",
     "to_chrome_trace",
     "validate_report",
