@@ -14,6 +14,8 @@ and launch code, so they all live here:
   * ``jax.shard_map`` with ``check_vma``/``axis_names`` -> ``shard_map()``.
   * Pallas interpret-mode selection on the CPU -> ``resolve_interpret()``.
   * Described TPU topologies (compile without a chip) -> ``tpu_topology()``.
+  * Array layout constraints (``jax.experimental.layout``) ->
+    ``with_row_major_layout()``.
 
 Policy (enforced by scripts/check_compat_imports.py, run as a tier-1
 test): no module outside this file may reference the raw symbols
@@ -38,6 +40,7 @@ __all__ = [
     "donated_jit",
     "aot_compile",
     "tpu_topology",
+    "with_row_major_layout",
 ]
 
 
@@ -110,7 +113,8 @@ def cost_analysis(compiled) -> Dict[str, float]:
 # ------------------------------------------------- donation / AOT jit
 
 def donated_jit(fn, *, donate_argnums: Tuple[int, ...] = (),
-                static_argnums: Tuple[int, ...] = ()):
+                static_argnums: Tuple[int, ...] = (),
+                platform: Optional[str] = None):
     """``jax.jit`` with buffer donation, requested only where the
     backend honours it.
 
@@ -121,9 +125,10 @@ def donated_jit(fn, *, donate_argnums: Tuple[int, ...] = (),
     timed region identical across backends without drowning CPU runs in
     warnings; the *semantics* (caller must not reuse donated args) are
     the same either way, so code tested on CPU is donation-correct on
-    TPU.
+    TPU.  ``platform`` is the one the program is compiled for, where
+    that is not the default backend's (a described chip).
     """
-    if jax.default_backend() not in ("tpu", "gpu"):
+    if (platform or jax.default_backend()) not in ("tpu", "gpu"):
         donate_argnums = ()
     return jax.jit(fn, donate_argnums=donate_argnums,
                    static_argnums=static_argnums)
@@ -136,6 +141,17 @@ def aot_compile(jitted, *args, **kwargs):
     serving loop compiles before its timed region starts.
     """
     return jitted.lower(*args, **kwargs).compile()
+
+
+def with_row_major_layout(x):
+    """``x`` held in the row-major layout (with the TPU's own tiling),
+    the one a TPU program takes and returns arrays in by default, where
+    the program is lowered for a TPU; elsewhere ``x`` as it is."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+    layout = Layout(major_to_minor=tuple(range(x.ndim)))
+    return jax.lax.platform_dependent(
+        x, tpu=lambda y: with_layout_constraint(y, layout),
+        default=lambda y: y)
 
 
 def tpu_topology(name: str):

@@ -128,19 +128,20 @@ def compile_step_fns(cfg, params, batch, opts: RunOptions,
     def decode_step(p, c, t, i):
         return lm_mod.decode_step(cfg, p, c, t, i, opts)
 
+    device = next(iter(jax.tree.leaves(params)[0].sharding.device_set))
     t0 = time.monotonic()
     prefill_c = compat.aot_compile(jax.jit(prefill), params, batch)
     t1 = time.monotonic()
-    logits0, cache0 = prefill_c(params, batch)
-    tok0 = jnp.argmax(logits0[:, :cfg.vocab_size], axis=-1)
-    t2 = time.monotonic()
+    _, cache = jax.eval_shape(prefill, params, batch)
+    tok = jax.ShapeDtypeStruct(batch["tokens"].shape[:1], jnp.int32)
     step_c = compat.aot_compile(
-        compat.donated_jit(decode_step, donate_argnums=(1,)), params,
-        cache0, tok0, jnp.int32(prompt_len))
-    t3 = time.monotonic()
+        compat.donated_jit(decode_step, donate_argnums=(1,),
+                           platform=device.platform),
+        params, cache, tok, jnp.int32(prompt_len))
+    t2 = time.monotonic()
     return (StepProgram(prefill_c, "prefill_dispatch"),
             StepProgram(step_c, "decode_dispatch"),
-            {"prefill": t1 - t0, "decode": t3 - t2})
+            {"prefill": t1 - t0, "decode": t2 - t1})
 
 
 def parse_args(argv=None) -> argparse.Namespace:

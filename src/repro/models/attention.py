@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.configs.base import AttentionConfig
 from repro.models.common import apply_rope, rmsnorm, rmsnorm_spec
 from repro.models.spec import Par
@@ -215,30 +216,45 @@ def self_attention(p: dict, x: jax.Array, a: AttentionConfig,
 
 
 def decode_attention(p: dict, x: jax.Array, a: AttentionConfig,
-                     cache_k: jax.Array, cache_v: jax.Array,
+                     cache_k: jax.Array, cache_v: jax.Array, layer,
                      pos, *, theta, window):
-    """Single-token decode.  x: [B, 1, d]; cache_k/v: [B, L, KV, hd];
-    ``pos`` is the (traced) index of the new token.
+    """Single-token decode.  x: [B, 1, d]; cache_k/v: [N, B, KV, hd, L],
+    the stacked caches of N layers (``blocks.kv_cache_spec``), of which
+    this one is ``layer``; ``pos`` is the (traced) index of the new token.
 
-    If the cache is SHORTER than the attention span could be (windowed
-    ring buffer, L == window for a local layer), the write lands at
-    pos % L and per-slot positions are reconstructed — slot s holds the
-    newest position p <= pos with p % L == s.  Returns
-    (y [B,1,d], new_cache_k, new_cache_v)."""
+    Only the new token's row is written, in place in the stack, and
+    attention reads this layer's slice by index, so no layer's cache is
+    copied out or written back whole.  If the cache is SHORTER than the
+    attention span could be (windowed ring buffer, L == window for a
+    local layer), the write lands at pos % L and per-slot positions are
+    reconstructed — slot s holds the newest position p <= pos with
+    p % L == s.  Returns (y [B,1,d], new_cache_k, new_cache_v)."""
     scale = a.softmax_scale or 1.0 / math.sqrt(a.head_dim)
     positions = jnp.asarray(pos, jnp.int32)[None]
     q, k_new, v_new = qkv_project(p, x, a, positions, theta)
     zero = jnp.zeros((), jnp.int32)
     pos_i = jnp.asarray(pos, jnp.int32)
-    L = cache_k.shape[1]
+    layer = jnp.asarray(layer, jnp.int32)
+    L = cache_k.shape[-1]
     is_ring = window > 0 and L <= window if isinstance(window, int) \
         else False
     slot = pos_i % L if is_ring else pos_i
     with jax.named_scope(ATTN_CORE):
+        at = (layer, zero, zero, zero, slot)
         cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (zero, slot, zero, zero))
+            cache_k, jnp.moveaxis(k_new, 1, -1)[None].astype(cache_k.dtype),
+            at)
         cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (zero, slot, zero, zero))
+            cache_v, jnp.moveaxis(v_new, 1, -1)[None].astype(cache_v.dtype),
+            at)
+        # attention reads the layer in the layout it is stored in, so
+        # the read fuses into its products; where the head dim fills the
+        # lanes the compiler would otherwise copy the layer out into a
+        # head-dim-minor layout first
+        k = compat.with_row_major_layout(
+            jax.lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False))
+        v = compat.with_row_major_layout(
+            jax.lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False))
     s_idx = jnp.arange(L, dtype=jnp.int32)
     if is_ring:
         # newest position in each slot; slots "ahead" of pos wrap to
@@ -246,8 +262,9 @@ def decode_attention(p: dict, x: jax.Array, a: AttentionConfig,
         pos_k = pos_i - ((pos_i - s_idx) % L)
     else:
         pos_k = s_idx
-    o = sdpa(q, cache_k, cache_v, positions, pos_k, causal=True,
-             window=window, scale=scale, chunk_q=0, chunk_kv=0)
+    o = sdpa(q, jnp.moveaxis(k, -1, 1), jnp.moveaxis(v, -1, 1), positions,
+             pos_k, causal=True, window=window, scale=scale, chunk_q=0,
+             chunk_kv=0)
     return out_project(p, o), cache_k, cache_v
 
 

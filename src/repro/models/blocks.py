@@ -177,6 +177,17 @@ def stage_spec(cfg: ModelConfig, stage: StageDescr) -> dict:
 # cache specs (decode state)
 
 
+def kv_cache_spec(cfg: ModelConfig, batch: int, L: int) -> Par:
+    """One layer's attention K or V cache, [B, KV, hd, L]: sequence
+    last, so that the row-major layout every program takes by default
+    is the sequence-minor one in which the decode step writes a row in
+    place and attention reads it (``attention.decode_attention``)."""
+    a = cfg.attention
+    return Par((batch, a.num_kv_heads, a.head_dim, L),
+               ("batch", "kv_heads", None, "kv_seq"), init="zeros",
+               dtype=cfg.dtype)
+
+
 def layer_cache_spec(cfg: ModelConfig, dsc: LayerDescr, batch: int,
                      cache_len: int, windowed: bool = False) -> dict:
     dt = cfg.dtype
@@ -190,22 +201,14 @@ def layer_cache_spec(cfg: ModelConfig, dsc: LayerDescr, batch: int,
             # local:global archs like gemma3 (see §Perf).
             L = min(cache_len, dsc.window)
         return {
-            "k": Par((batch, L, a.num_kv_heads, a.head_dim),
-                     ("batch", "kv_seq", "kv_heads", None), init="zeros",
-                     dtype=dt),
-            "v": Par((batch, L, a.num_kv_heads, a.head_dim),
-                     ("batch", "kv_seq", "kv_heads", None), init="zeros",
-                     dtype=dt),
+            "k": kv_cache_spec(cfg, batch, L),
+            "v": kv_cache_spec(cfg, batch, L),
         }
     if dsc.kind == "dec_attn":
         ek = cfg.encdec.cross_kv_len
         return {
-            "k": Par((batch, cache_len, a.num_kv_heads, a.head_dim),
-                     ("batch", "kv_seq", "kv_heads", None), init="zeros",
-                     dtype=dt),
-            "v": Par((batch, cache_len, a.num_kv_heads, a.head_dim),
-                     ("batch", "kv_seq", "kv_heads", None), init="zeros",
-                     dtype=dt),
+            "k": kv_cache_spec(cfg, batch, cache_len),
+            "v": kv_cache_spec(cfg, batch, cache_len),
             "ck": Par((batch, ek, a.num_kv_heads, a.head_dim),
                       ("batch", None, "kv_heads", None), init="zeros",
                       dtype=dt),
@@ -216,14 +219,8 @@ def layer_cache_spec(cfg: ModelConfig, dsc: LayerDescr, batch: int,
     if dsc.kind == "mamba":
         c = ssm_mod.mamba_state_spec(batch, cfg.d_model, cfg.ssm, dt)
         if dsc.shared_attn:
-            c["shared_k"] = Par(
-                (batch, cache_len, a.num_kv_heads, a.head_dim),
-                ("batch", "kv_seq", "kv_heads", None), init="zeros",
-                dtype=dt)
-            c["shared_v"] = Par(
-                (batch, cache_len, a.num_kv_heads, a.head_dim),
-                ("batch", "kv_seq", "kv_heads", None), init="zeros",
-                dtype=dt)
+            c["shared_k"] = kv_cache_spec(cfg, batch, cache_len)
+            c["shared_v"] = kv_cache_spec(cfg, batch, cache_len)
         return c
     if dsc.kind == "rwkv":
         return rwkv_mod.rwkv_state_spec(batch, cfg.d_model, cfg.rwkv, dt)

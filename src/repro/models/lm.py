@@ -27,7 +27,7 @@ from repro.models import ffn as ffn_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import rmsnorm, rmsnorm_spec
-from repro.models.spec import Par, init_tree, stack
+from repro.models.spec import Par, init_tree, is_par, stack
 from repro.obs.blocks import ATTN_CORE, HEAD
 
 MAX_POS_TABLE = 32_768  # whisper learned-position tables
@@ -207,6 +207,8 @@ def lm_loss(cfg: ModelConfig, params: dict, x: jax.Array,
 def _to_cache_buf(k: jax.Array, cache_len: int,
                   opts: RunOptions = DEFAULT_OPTS,
                   window: int = 0) -> jax.Array:
+    """Prefill's k or v [B, S, KV, hd] as a cache buffer [B, L, KV, hd];
+    ``_run_stage_full`` moves the stack to the cache's order."""
     if opts.windowed_cache and window > 0:
         L = min(cache_len, window)
         S = k.shape[1]
@@ -335,6 +337,18 @@ def _apply_unit_full(cfg: ModelConfig, up: dict, unit, x, x0, positions,
     return x, aux, (cache if collect else None)
 
 
+def _split_kv(cfg: ModelConfig, stage: blk.StageDescr, tree: dict):
+    """Split a stage's cache tree into its attention K/V leaves
+    (those whose cache spec has a ``kv_seq`` axis) and the rest."""
+    spec = blk.stage_cache_spec(cfg, stage, 1, 1)
+    kv = {u: {n: l for n, l in c.items()
+              if is_par(spec[u][n]) and "kv_seq" in spec[u][n].axes}
+          for u, c in tree.items()}
+    rest = {u: {n: l for n, l in c.items() if n not in kv[u]}
+            for u, c in tree.items()}
+    return kv, rest
+
+
 def _run_stage_full(cfg, sp, stage: blk.StageDescr, x, x0, positions, opts,
                     collect: bool, memory, shared, cache_len: int):
     idxs = jnp.arange(stage.n_units, dtype=jnp.int32)
@@ -362,6 +376,15 @@ def _run_stage_full(cfg, sp, stage: blk.StageDescr, x, x0, positions, opts,
             cl.append(ci)
         caches = (jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
                   if collect else None)
+    if collect:
+        # K/V stacks [N, B, L, KV, hd] to the cache's order, sequence
+        # last (``blocks.kv_cache_spec``), once and after the loop: moved
+        # inside it, each layer's transposed buffer took the on-chip
+        # memory the attention's chunks had (a slower prefill)
+        kv, rest = _split_kv(cfg, stage, caches)
+        caches = {u: {**rest[u], **{n: jnp.moveaxis(c, 2, -1)
+                                    for n, c in kv[u].items()}}
+                  for u in kv}
     return x, aux, caches
 
 
@@ -443,8 +466,8 @@ def _apply_unit_decode(cfg: ModelConfig, up: dict, unit, x, x0, pos,
         if dsc.kind in ("attn", "enc_attn"):
             h = rmsnorm(x, p["ln_attn"])
             att, nk, nv = attn_mod.decode_attention(
-                p["attn"], h, a, c["k"], c["v"], pos, theta=dsc.theta,
-                window=dsc.window)
+                p["attn"], h, a, c["k"], c["v"], unit_idx, pos,
+                theta=dsc.theta, window=dsc.window)
             if cfg.use_post_norm:
                 att = rmsnorm(att, p["ln_attn_post"])
             x = x + att
@@ -462,7 +485,8 @@ def _apply_unit_decode(cfg: ModelConfig, up: dict, unit, x, x0, pos,
         elif dsc.kind == "dec_attn":
             h = rmsnorm(x, p["ln_self"])
             att, nk, nv = attn_mod.decode_attention(
-                p["self"], h, a, c["k"], c["v"], pos, theta=0.0, window=0)
+                p["self"], h, a, c["k"], c["v"], unit_idx, pos, theta=0.0,
+                window=0)
             x = x + att
             h = rmsnorm(x, p["ln_cross"])
             x = x + attn_mod.cross_attention(p["cross"], h, c["ck"],
@@ -477,8 +501,8 @@ def _apply_unit_decode(cfg: ModelConfig, up: dict, unit, x, x0, pos,
                 cat = jnp.concatenate([x, x0], axis=-1)
                 h = rmsnorm(cat, sp["ln_in"])
                 att, sk, sv = attn_mod.decode_attention(
-                    sp["attn"], h, a, c["shared_k"], c["shared_v"], pos,
-                    theta=a.rope_theta, window=0)
+                    sp["attn"], h, a, c["shared_k"], c["shared_v"],
+                    unit_idx, pos, theta=a.rope_theta, window=0)
                 x = x + att
                 h2 = rmsnorm(x, sp["ln_ffn"])
                 x = x + ffn_mod.dense_ffn(sp["ffn"], h2, cfg.activation)
@@ -508,7 +532,13 @@ def _apply_unit_decode(cfg: ModelConfig, up: dict, unit, x, x0, pos,
 def decode_step(cfg: ModelConfig, params: dict, cache: dict,
                 token: jax.Array, pos, opts: RunOptions = DEFAULT_OPTS):
     """One decode step.  token: [B] int32; pos: scalar position of the
-    new token.  Returns (fp32 logits [B, padded_vocab], new cache)."""
+    new token.  Returns (fp32 logits [B, padded_vocab], new cache).
+
+    The layer loop carries each stage's stacked attention K/V caches
+    whole, and each layer writes only the new token's row into them in
+    place (``attention.decode_attention``); the small per-layer states
+    (SSM, RWKV, cross-attention memory) go through the loop as per-layer
+    inputs and outputs."""
     x = _embed(cfg, params, token[:, None], None, opts)
     if cfg.family == "encdec":
         x = x + jax.lax.dynamic_slice_in_dim(
@@ -521,23 +551,28 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
     for si, st in enumerate(blk.build_stages(cfg)):
         sp = params[f"stage{si}"]
         idxs = jnp.arange(st.n_units, dtype=jnp.int32)
+        kv, rest = _split_kv(cfg, st, cache[f"stage{si}"])
 
-        def body(xx, inp, _st=st):
+        def body(carry, inp, _st=st):
+            xx, kv = carry
             up, ui, cu = inp
+            both = {u: {**cu[u], **kv[u]} for u in cu}
             xx, nc = _apply_unit_decode(cfg, up, _st.unit, xx, x0, pos,
-                                        opts, cu, shared, ui)
-            return xx, nc
+                                        opts, both, shared, ui)
+            kv, nc = _split_kv(cfg, _st, nc)
+            return (xx, kv), nc
 
         if scan_units:
-            x, nc = jax.lax.scan(body, x, (sp, idxs, cache[f"stage{si}"]))
+            (x, kv), rest = jax.lax.scan(body, (x, kv), (sp, idxs, rest))
         else:
             ncl = []
             for i in range(st.n_units):
-                x, ci = body(x, (blk.tree_index(sp, i), jnp.int32(i),
-                                 blk.tree_index(cache[f"stage{si}"], i)))
+                (x, kv), ci = body((x, kv), (blk.tree_index(sp, i),
+                                             jnp.int32(i),
+                                             blk.tree_index(rest, i)))
                 ncl.append(ci)
-            nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncl)
-        new_caches[f"stage{si}"] = nc
+            rest = jax.tree.map(lambda *xs: jnp.stack(xs), *ncl)
+        new_caches[f"stage{si}"] = {u: {**rest[u], **kv[u]} for u in kv}
     with jax.named_scope(HEAD):
         x = rmsnorm(x, params["final_norm"])
     logits = compute_logits(cfg, params, x[:, 0])

@@ -8,10 +8,10 @@ GC-quiesced ``measure_callable`` the kernel tuner uses, so a warm
 cache still means zero measurement spans on the trace.
 
 Compilation is hoisted out of the timed region entirely: the runner
-builds params once, AOT-compiles prefill and the decode step
-(``compat.aot_compile``), and the thunk only executes the compiled
-programs.  That is what lets p99/CoV of the pass speak for the plan
-rather than for compile jitter.
+builds params once, AOT-compiles prefill and the decode step as the
+server does (``launch.serve.compile_step_fns``), and the thunk only
+executes the compiled programs.  That is what lets p99/CoV of the pass
+speak for the plan rather than for compile jitter.
 """
 from __future__ import annotations
 
@@ -56,7 +56,7 @@ def make_serve_runner(cfg, problem: ModelProblem,
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
+    from repro.launch.serve import compile_step_fns
     from repro.models import lm as lm_mod
     from repro.models.lm import RunOptions
 
@@ -73,16 +73,7 @@ def make_serve_runner(cfg, problem: ModelProblem,
     if cfg.family == "encdec":
         batch["frames"] = jax.random.normal(key, (B, P, cfg.d_model))
 
-    prefill_j = jax.jit(lambda p, b: lm_mod.prefill(cfg, p, b, opts))
-    step_j = compat.donated_jit(
-        lambda p, c, t, i: lm_mod.decode_step(cfg, p, c, t, i, opts),
-        donate_argnums=(1,))
-    prefill_c = compat.aot_compile(prefill_j, params, batch)
-    logits0, cache0 = prefill_c(params, batch)
-    tok0 = jnp.argmax(logits0[:, :cfg.vocab_size], axis=-1)
-    step_c = compat.aot_compile(step_j, params, cache0, tok0,
-                                jnp.int32(P))
-    del logits0, cache0, tok0
+    prefill_c, step_c, _ = compile_step_fns(cfg, params, batch, opts, P)
 
     def run() -> None:
         logits, cache = prefill_c(params, batch)

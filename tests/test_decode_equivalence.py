@@ -73,8 +73,8 @@ def test_windowed_ring_cache_matches_full(monkeypatch):
     opts = RunOptions(chunk_q=0, chunk_kv=0, cache_len=42, remat=False,
                       windowed_cache=True)
     lg, cache = prefill(cfg, params, {"tokens": toks[:, :32]}, opts)
-    assert cache["stage0"]["pos0"]["k"].shape[2] == 8   # ring!
-    assert cache["stage0"]["pos5"]["k"].shape[2] == 42  # global: full
+    assert cache["stage0"]["pos0"]["k"].shape[-1] == 8   # ring!
+    assert cache["stage0"]["pos5"]["k"].shape[-1] == 42  # global: full
     for t in range(10):
         lg, cache = decode_step(cfg, params, cache, toks[:, 32 + t],
                                 32 + t, opts)

@@ -89,6 +89,45 @@ def test_serve_run_reports_a_deadline_shed():
     assert r["deadline"]["n_shed"] >= 1
 
 
+def test_step_takes_the_prefills_cache_and_then_its_own():
+    """The compiled decode step takes the compiled prefill's cache, and
+    then its own, in the layout each returns it in: each attention K/V
+    leaf sequence-last (``blocks.kv_cache_spec``) in the default layout;
+    its logits and cache are the model's own step's."""
+    import numpy as np
+
+    from conftest import tiny_cfg
+    from repro.launch import serve
+    from repro.models import lm
+    from repro.models.lm import RunOptions
+    cfg = tiny_cfg("qwen2-0.5b", num_layers=2, dtype="float32")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 16),
+                                          0, cfg.vocab_size)}
+    opts = RunOptions(chunk_q=8, chunk_kv=8, cache_len=20, remat=False,
+                      decode_scan=True)
+    prefill, step, _ = serve.compile_step_fns(cfg, params, batch, opts, 16)
+    fmt = step.compiled.input_formats[0][1]
+    assert prefill.compiled.output_formats[1] == fmt
+    assert step.compiled.output_formats[1] == fmt
+    logits, cache = prefill(params, batch)
+    _, want_cache = lm.prefill(cfg, params, batch, opts)
+    assert cache["stage0"]["pos0"]["k"].shape == (2, 2, 2, 32, 20)
+    assert cache["stage0"]["pos0"]["k"].shape == \
+        want_cache["stage0"]["pos0"]["k"].shape
+    for i in range(4):
+        tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
+        logits, cache = step(params, cache, tok, jnp.int32(16 + i))
+        want, want_cache = lm.decode_step(cfg, params, want_cache, tok,
+                                          16 + i, opts)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    assert jax.tree.map(lambda c: c.format, cache) == fmt
+    for g, w in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_serve_run_refuses_unknown_tpu_kind(monkeypatch):
     """On a TPU that the peak table lacks, serving raises before it
     prices a bound against the wrong chip."""

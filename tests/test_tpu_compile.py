@@ -5,7 +5,10 @@ widths (the shapes ``chip_smoke.py`` serves) and the Pallas kernels at
 qwen2-0.5b's projection and attention shapes, for one chip of a
 *described* v5e:2x2 topology.  Nothing runs, so this says nothing about
 results or times; it catches what interpret mode cannot: VMEM
-overflows, unaligned tiles, programs that do not fit in HBM.
+overflows, unaligned tiles, programs that do not fit in HBM.  It also
+reads the structure of the served decode step at the benchmark's decode
+shapes: the stacked KV cache is updated in place, with no copy or slice
+of a layer's cache or of the whole stack.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every xdist worker
@@ -13,7 +16,10 @@ imports every test module.  Keep these compiles in this one file.
 ``wkv6`` is absent on purpose: its kernel does not lower for the chip
 (see ROADMAP design debts).
 """
+import dataclasses
+import math
 import os
+import re
 
 import pytest
 
@@ -23,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import compat
 from repro.configs import get_config
+from repro.launch import serve
 from repro.models import lm
 from repro.models.lm import RunOptions
 
@@ -87,6 +94,90 @@ def test_qwen2_serve_step_compiles_for_v5e(one_chip, qwen2_shapes,
                               pos).compile()
     used = _device_bytes(compiled)
     assert 0 < used < HBM_BYTES, used
+
+
+def _scheduled_ops(text: str):
+    """``(name, opcode, result dims)`` of every instruction outside the
+    fused computations of an optimized HLO module's text."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%?([\w.\-]+)", text))
+    comp, out = None, []
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.endswith("{"):
+            comp = line.replace("ENTRY ", "").lstrip("%").split()[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*)", line)
+        if comp in fused or not m:
+            continue
+        rest = m.group(2)
+        if rest.startswith("("):     # a tuple: its type ends at its ")"
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            ty, rest = rest[:i + 1], rest[i + 1:]
+        else:
+            ty, _, rest = rest.partition(" ")
+        op = re.match(r"\s*([\w\-]+)\(", rest)
+        for dims in re.findall(r"\w+\[([\d,]*)\]", ty):
+            shape = [int(d) for d in dims.split(",") if d]
+            while shape and shape[0] == 1:
+                shape = shape[1:]
+            out.append((m.group(1), op.group(1) if op else "", shape))
+    return out
+
+
+def _moves(ops, shapes):
+    """The copies and slices among ``ops`` whose result is one of
+    ``shapes``; fusions are named after the operations fused in them."""
+    return [name for name, op, shape in ops if shape in shapes and (
+        op in ("copy", "copy-start", "copy-done", "dynamic-slice")
+        or op == "fusion" and ("copy" in name or "dynamic-slice" in name))]
+
+
+DECODE_CELLS = {   # the benchmark's decode cells: batch, prompt, gen
+    "qwen2-0.5b": (get_config("qwen2-0.5b"), 128, 256, 1792),
+    "deepseek-67b-l4": (dataclasses.replace(get_config("deepseek-67b"),
+                                            num_layers=4), 32, 512, 512),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+def test_decode_step_updates_the_stacked_cache_in_place(one_chip, cell):
+    """The served decode step (``serve.compile_step_fns``) writes one
+    row into the stacked K/V caches and reads each layer inside
+    attention: no copy or slice of a layer's cache or of the whole
+    stack, and no cache-sized temporary.  Its temporaries stay below
+    one layer's K cache plus one layer's largest weight (the layer
+    loop's slice of the stacked weights).  The prefill hands the step
+    its cache in the layout the step takes, and the step returns it so:
+    the device's default layout, which a program loaded from JAX's
+    persistent compilation cache keeps."""
+    cfg, b, p, g = DECODE_CELLS[cell]
+    opts = RunOptions(chunk_q=512, chunk_kv=512, cache_len=p + g,
+                      remat=False, decode_scan=True)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct((b, p), jnp.int32,
+                                            sharding=one_chip)}
+    prefill, step, _ = serve.compile_step_fns(cfg, params, batch, opts, p)
+    fmt = step.compiled.input_formats[0][1]
+    assert prefill.compiled.output_formats[1] == fmt
+    assert step.compiled.output_formats[1] == fmt
+    for f in jax.tree.leaves(fmt):
+        order = f.layout.major_to_minor
+        assert order == tuple(sorted(order)), order
+
+    a = cfg.attention
+    layer = [b, a.num_kv_heads, a.head_dim, p + g]
+    moves = _moves(_scheduled_ops(step.compiled.as_text()),
+                   [layer, [cfg.num_layers] + layer])
+    assert moves == [], moves
+    bf16 = 2
+    weight = max(math.prod(w.shape[1:]) * w.dtype.itemsize
+                 for w in jax.tree.leaves(params["stage0"]))
+    temp = step.compiled.memory_analysis().temp_size_in_bytes
+    assert temp < math.prod(layer) * bf16 + weight, temp
 
 
 @pytest.mark.parametrize("m,k,n", [(256, 896, 4864), (512, 4864, 896)])
