@@ -1,8 +1,9 @@
 """From a profiler trace to the step programs' device time by block, and
 the host's time to dispatch each decode step.
 
-The program names its blocks with ``jax.named_scope`` (``BLOCKS``, as
-``repro.obs.BLOCKS`` holds them) and calls each compiled step program
+The program names its blocks with ``jax.named_scope`` (today
+``repro.obs.BLOCKS``: ``attn_proj``, ``attn_core``, ``ffn``, ``head``;
+this module keeps no copy of them) and calls each compiled step program
 inside a host annotation of its own (``DISPATCH``, made by
 ``repro.launch.serve.compile_step_fns``).  A device trace names an
 operation only by its HLO instruction, so the block of an operation
@@ -18,16 +19,18 @@ the trace, through ``repro.obs.op_blocks``.
   host annotations in the window); their union (``busy_s``, the same
   number as ``tracing.reduce``'s ``steps[...]["busy_s"]``); and each
   operation's self time (``tracing._self_times``: a ``while`` keeps
-  only the time in which none of its body runs) summed by the block
-  the program's map gives the operation, ``other`` where the map has
-  none (``blocks``; ``other_ops`` holds those operations' own times by
-  name).  Where no two operations of a chip overlap but by
+  only the time in which none of its body runs) summed by the block,
+  whatever its name, that the program's map gives the operation,
+  ``other`` where the map has none (``blocks``: every block the map
+  names, and ``other``; ``other_ops`` holds those operations' own times
+  by name).  Where no two operations of a chip overlap but by
   nesting, the blocks sum to ``busy_s``.  Seconds, averaged over the
   chips.  Besides, the length of each ``decode_dispatch`` annotation in
   the window (``dispatch_s``), on the host's clock alone.
-- ``per_step_ms`` and ``launch_ms`` give what a metric reads: ms per
-  traced step, or None where the trace's count of steps is not what
-  the harness served.
+- ``per_step_ms`` and ``launch_ms`` give ms per traced step, or None
+  where the trace's count of steps is not what the harness served;
+  ``read_ms`` is what a block metric (``metrics/<program>_<block>_ms.py``)
+  reads from a run's record.
 
 The dispatch is read as the host's span, not as the device's idle time
 inside it: in a TPU trace the device's clock is offset from the host's
@@ -48,7 +51,6 @@ import numpy as np
 
 from chipbench import tracing
 
-BLOCKS = ("attn_proj", "attn_core", "ffn", "head")
 MATMUL = ("attn_proj", "ffn", "head")     # the blocks that are matmuls
 OTHER = "other"
 DISPATCH = ("prefill_dispatch", "decode_dispatch")
@@ -136,7 +138,8 @@ def reduce(events: list[tracing.Event], maps: dict | None) -> dict | None:
     for v in spans.values():
         v.sort()
     steps = {k: {"n": len(spans[k]), "busy_s": 0.0,
-                 "blocks": dict.fromkeys(BLOCKS + (OTHER,), 0.0),
+                 "blocks": dict.fromkeys(
+                     sorted(set(maps[k].values())) + [OTHER], 0.0),
                  "other_ops": defaultdict(float)}
              for k in tracing.STEPS}
     n = len(ops)
@@ -166,6 +169,19 @@ def per_step_ms(red: dict | None, program: str, served: int) -> dict | None:
     if s["n"] != served:
         return None
     return {b: 1e3 * v / served for b, v in s["blocks"].items()}
+
+
+def read_ms(r, program: str, names) -> float | None:
+    """The ms per traced step of ``program`` in the blocks ``names``,
+    summed, from ``r.blocks`` of a run's record (``record.Record``); None
+    where the trace's count of steps is not what the run served, or the
+    program's map names none of them."""
+    served = r.traced_prefills if program == "prefill" \
+        else len(r.traced_positions)
+    ms = per_step_ms(r.blocks, program, served)
+    if ms is None or not set(names) & set(ms):
+        return None
+    return sum(ms.get(b, 0.0) for b in names)
 
 
 def launch_ms(red: dict | None, served: int) -> float | None:

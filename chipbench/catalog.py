@@ -1,13 +1,18 @@
 """Finds every piece of a cell by the names in ``BENCHMARK.json``:
 
 - ``chipbench/configs/<config>.json`` (the configuration's ``file``),
+  which names its architecture's module with ``"reference": "<name>"``,
+- ``chipbench/reference/<name>.py``, the one module that knows the
+  architecture: the sizes, the program's config and parameters, the
+  counts of the ``*_mfu`` metrics and the reference (the docstring of
+  each module there lists what it exposes),
 - ``chipbench/traffic/<traffic>.json``,
 - ``chipbench/limits/<workload>.json`` (the limit of each number that
   decides ``correct``),
 - ``chipbench/metrics/<metric>.py``, exposing ``read(record)``.
 
-A later cell, traffic mix, limit or metric is a new file; nothing here
-changes.  A traffic mix is closed batches: ``batch`` rows, each a
+A later cell, traffic mix, limit, metric or architecture is a new file;
+nothing here changes.  A traffic mix is closed batches: ``batch`` rows, each a
 prompt of ``prompt_len`` tokens and ``gen`` greedy tokens, all arriving
 at the batch's start, the next batch once the last token of the one
 before reached the host; ``check_requests`` of its finished requests
@@ -19,6 +24,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import pathlib
+import types
 from dataclasses import dataclass
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -29,6 +35,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    arch: types.ModuleType       # reference/<config["reference"]>.py
     traffic: dict
     limits: dict
     end_to_end: list
@@ -63,21 +70,43 @@ def cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
     w = cells[workload]
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     base = root / "chipbench"
+    config = _json(root / cfg["file"])
     return Cell(
-        name=workload, chips=int(w["chips"]),
-        config=_json(root / cfg["file"]),
+        name=workload, chips=int(w["chips"]), config=config,
+        arch=architecture(config, root / cfg["file"], root),
         traffic=traffic(base / "traffic" / f"{w['traffic']}.json"),
         limits=_json(base / "limits" / f"{workload}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)])
 
 
-def reader(metric: str, root: pathlib.Path = ROOT):
-    """``read(record) -> float | None`` of ``metrics/<metric>.py``."""
-    path = root / "chipbench" / "metrics" / f"{metric}.py"
+def _load(path: pathlib.Path, kind: str, name: str) -> types.ModuleType:
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_').replace('-', '_')}",
+        f"chipbench_{kind}_{name.replace('.', '_').replace('-', '_')}",
         path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def architecture(config: dict, source, root: pathlib.Path = ROOT):
+    """The module ``reference/<name>.py`` that the configuration
+    ``config`` (read from the file ``source``) names under
+    ``"reference"``; a missing key or module is an error that names
+    ``source``."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(f"{source}: no \"reference\" key naming the "
+                         f"architecture's module (chipbench/reference/"
+                         f"<name>.py); it reads {name!r}")
+    path = root / "chipbench" / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{source}: \"reference\": {name!r} names "
+                                f"no module; {path} does not exist")
+    return _load(path, "reference", name)
+
+
+def reader(metric: str, root: pathlib.Path = ROOT):
+    """``read(record) -> float | None`` of ``metrics/<metric>.py``."""
+    return _load(root / "chipbench" / "metrics" / f"{metric}.py", "metric",
+                 metric).read

@@ -12,11 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import catalog, check, loop, model, tracing
-from chipbench import weights as W
+from chipbench import blocks, catalog, check, loop, tracing
 from chipbench.peaks import peaks_for
 from chipbench.record import Record, window_record
-from chipbench.reference.dense_gqa import Reference
 
 TRACE_S = 2.0        # length of the traced segment after the window
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
@@ -44,9 +42,10 @@ def prompt_source(traffic: dict, vocab: int, seed: int):
     return lambda: rng.integers(0, vocab, shape, dtype=np.int32)
 
 
-def build_server(cfg, m: dict, traffic: dict, seed: int, log=print):
-    """The program's serving plan, weights and compiled steps for the
-    cell's shapes (as ``serve.run`` resolves and compiles them)."""
+def build_server(arch, cfg, m: dict, traffic: dict, seed: int, log=print):
+    """The program's serving plan, weights (``arch.make_params``) and
+    compiled steps for the cell's shapes (as ``serve.run`` resolves and
+    compiles them)."""
     from repro.launch import serve
     from repro.models.lm import RunOptions
     from repro.tuning.model import ModelProblem, resolve_model_plan
@@ -60,7 +59,7 @@ def build_server(cfg, m: dict, traffic: dict, seed: int, log=print):
                       chunk_kv=int(plan["chunk_kv"]), cache_len=P + G,
                       remat=False, decode_scan=bool(plan["decode_scan"]))
     t0 = time.monotonic()
-    params = jax.block_until_ready(model.make_params(cfg, m, seed))
+    params = jax.block_until_ready(arch.make_params(cfg, m, seed))
     t1 = time.monotonic()
     prompt = {"tokens": jnp.zeros((B, P), jnp.int32)}
     prefill, step, compile_s = serve.compile_step_fns(cfg, params, prompt,
@@ -71,6 +70,28 @@ def build_server(cfg, m: dict, traffic: dict, seed: int, log=print):
     return loop.Server(params=params, prefill=prefill, step=step,
                        sample=lambda logits: jnp.argmax(logits[:, :V], -1),
                        prompt_len=P, gen=G)
+
+
+def set_up(cell: catalog.Cell, seed: int, log=print, server_hook=None):
+    """The cell's server, warmed up on every shape and host-side op the
+    window will use, its sizes (``cell.arch.dims``) and its prompt
+    source.  ``server_hook(server)`` may replace parts of the timed path
+    (tests)."""
+    c, traffic = cell.config, cell.traffic
+    m = cell.arch.dims(c)
+    log(f"cell {cell.name}: {c['name']} layers {m['layers']} d {m['d']} "
+        f"vocab {m['vocab']}; batch {traffic['batch']} prompt "
+        f"{traffic['prompt_len']} gen {traffic['gen']}; seed {seed}")
+    server = build_server(cell.arch, cell.arch.program_config(c), m,
+                          traffic, seed, log)
+    if server_hook is not None:
+        server_hook(server)
+    warm = loop.Batch(server, np.zeros((traffic["batch"],
+                                        traffic["prompt_len"]), np.int32))
+    warm.start()
+    warm.step()
+    warm.step()
+    return server, m, prompt_source(traffic, m["vocab"], seed)
 
 
 class GcWatch:
@@ -114,28 +135,13 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool,
     def log(msg):
         print(msg, flush=True)
 
-    c, traffic = cell.config, cell.traffic
+    traffic = cell.traffic
     devs = devices(cell.chips) if require_tpu else jax.devices()[:1]
     dev = devs[0]
     peaks = peaks_for(dev.device_kind) if require_tpu \
         else peaks_for("TPU v5 lite")
-    m = W.dims(c)
-    cfg = model.program_config(c)
-    log(f"cell {cell.name}: {c['name']} layers {m['layers']} d {m['d']} "
-        f"vocab {m['vocab']}; batch {traffic['batch']} prompt "
-        f"{traffic['prompt_len']} gen {traffic['gen']}; seed {seed}; "
-        f"device {dev.device_kind} x{len(devs)}")
-    server = build_server(cfg, m, traffic, seed, log)
-    if server_hook is not None:
-        server_hook(server)
-    # warm-up: every shape and host-side op the window will use
-    warm = loop.Batch(server, np.zeros((traffic["batch"],
-                                        traffic["prompt_len"]), np.int32))
-    warm.start()
-    warm.step()
-    warm.step()
-    del warm
-    prompts = prompt_source(traffic, m["vocab"], seed)
+    log(f"device {dev.device_kind} x{len(devs)}")
+    server, m, prompts = set_up(cell, seed, log, server_hook)
     counter, gcw = CompileCounter(), GcWatch()
     # what set-up made lives as long as the process: a full collection
     # would walk all of it (about 0.1 s) at some step of the window
@@ -143,7 +149,8 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool,
     gc.freeze()
 
     t_start = time.monotonic()
-    rec = Record(dims=m, peaks=peaks, batch=traffic["batch"],
+    rec = Record(arch=cell.arch, dims=m, peaks=peaks,
+                 batch=traffic["batch"],
                  prompt_len=traffic["prompt_len"], setup_s=t_start - t_proc)
     counter.on = gcw.on = True
     batches: list = []
@@ -190,8 +197,9 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool,
     del server
     gc.collect()
     t_ref = time.monotonic()
-    gaps = Reference(m).gaps(seed, jnp.asarray(seqs), jnp.asarray(served),
-                             (control,) if control else ())
+    gaps = cell.arch.Reference(m).gaps(seed, jnp.asarray(seqs),
+                                       jnp.asarray(served),
+                                       (control,) if control else ())
     readings = {k: float(np.max(v)) for k, v in gaps.items()}
     token_gap = readings[control or "program"]
     limit = float(cell.limits["token_gap"])
@@ -224,8 +232,9 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool,
 
 def traced_segment(server, prompts, inflight, batches, rec: Record):
     """Serve on for ``TRACE_S`` under the profiler; fills ``rec.trace``
-    with the trace's reduction and ``rec.traced_*`` with the prefills
-    and decode positions served, and returns the batch then in flight."""
+    and ``rec.blocks`` with the trace's reductions (``tracing``,
+    ``blocks``) and ``rec.traced_*`` with the prefills and decode
+    positions served, and returns the batch then in flight."""
     P = server.prompt_len
     going = inflight if inflight is not None and not inflight.done else None
     k0, n0 = (going.steps if going else 0), len(batches)
@@ -238,6 +247,7 @@ def traced_segment(server, prompts, inflight, batches, rec: Record):
                                   batches)
         jax.profiler.stop_trace()
         rec.trace = tracing.reduce(tracing.load(log_dir))
+        rec.blocks = blocks.reduce(blocks.load(log_dir), blocks.maps(server))
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)
     if going:

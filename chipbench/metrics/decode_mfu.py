@@ -1,11 +1,12 @@
 """The decode step program's share of its roofline, in %: the least
 time the decode steps of the traced segment could take at the chip's
 peaks (per step the larger of operations over peak FLOP/s and needed
-bytes over peak bandwidth, ``counts.decode_roofline_s``), summed, over
-the device time of those steps (``tracing.reduce``: the device
-operations inside the host's ``decode_step`` annotations).  The host's
-sampling and token transfer between steps is not in it; the device's
-idle share reads that."""
+bytes over peak bandwidth: ``decode_roofline_s`` of the cell's
+architecture, ``reference/<name>.py``), summed, over the device time of
+those steps (``tracing.reduce``: the device operations inside the
+host's ``decode_step`` annotations).  The host's sampling and token
+transfer between steps is not in it; the device's idle share reads
+that."""
 
 
 def read(r):

@@ -1,5 +1,6 @@
 """The prefill program's share of the chip's bf16 peak, in %: the
-operations a prefill needs (``counts.prefill_flops``) times the
+operations a prefill needs (``prefill_flops`` of the cell's
+architecture, ``reference/<name>.py``) times the
 prefills of the traced segment, over the device time of those prefills
 (``tracing.reduce``: the device operations inside the host's
 ``prefill`` annotations) times the peak.  The prompt's upload and the

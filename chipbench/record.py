@@ -3,14 +3,14 @@ arrival on the host, reduced to the lists the readers need, and the
 trace summary of a traced run with the steps it served."""
 from __future__ import annotations
 
+import types
 from dataclasses import dataclass, field
-
-from chipbench import counts
 
 
 @dataclass
 class Record:
-    dims: dict               # weights.dims of the configuration
+    arch: types.ModuleType   # the cell's architecture (catalog.Cell.arch)
+    dims: dict               # arch.dims of the configuration
     peaks: dict              # peaks.PEAKS entry of the chip
     batch: int
     prompt_len: int
@@ -21,17 +21,19 @@ class Record:
     prefill_s: list = field(default_factory=list)  # one per batch
     steps: list = field(default_factory=list)      # (position, seconds)
     trace: dict | None = None                      # tracing.reduce
+    blocks: dict | None = None                     # blocks.reduce
     # what the traced segment served: one prefill per batch it started,
     # and the position each of its decode steps wrote
     traced_prefills: int = 0
     traced_positions: list = field(default_factory=list)
 
     def prefill_flops(self) -> int:
-        return counts.prefill_flops(self.dims, self.batch, self.prompt_len)
+        return self.arch.prefill_flops(self.dims, self.batch,
+                                       self.prompt_len)
 
     def step_roofline_s(self, pos: int) -> float:
-        return counts.decode_roofline_s(self.dims, self.peaks, self.batch,
-                                        pos)
+        return self.arch.decode_roofline_s(self.dims, self.peaks, self.batch,
+                                           pos)
 
 
 def window_record(rec: Record, batches, t_start: float,

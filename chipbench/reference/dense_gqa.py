@@ -1,21 +1,45 @@
-"""Plain float32 reference of a dense decoder with grouped-query
-attention (Qwen2, LLaMA and DeepSeek-LLM), and its lower-precision
-control.
+"""A dense decoder with grouped-query attention (Qwen2, LLaMA and
+DeepSeek-LLM): the one module of the benchmark that knows this
+architecture.  A configuration file names it with
+``"reference": "dense_gqa"``, and the harness finds it by that name
+(``catalog.architecture``).  Like every architecture's module it
+exposes:
 
-Straight from the published architecture: token embedding; per layer
-``x += Wo . softmax(causal(q k^T / sqrt(hd))) v`` on the RMS-normed
-input, with rotary embeddings (rotate-half form, base ``rope_theta``) on
-q and k, optional q/k/v biases, and kv head ``h // (H / KV)`` serving
-query head ``h``; then ``x += W_down (silu(W_gate x) * W_up x)`` on the
-RMS-normed result; a final RMS norm; logits against the head (the
-embedding when tied).  Every matmul runs at ``Precision.HIGHEST``, so
-float32 is float32 on a TPU too.  No cache, no chunking, no batching
-beyond a map over sequences.
+- ``dims(c)``: the sizes of the configuration file ``c`` (at least
+  ``d``, ``layers`` and ``vocab``);
+- ``program_config(c)``: the program's ``ModelConfig``;
+- ``make_params(cfg, m, seed)``: the program's parameters from the seed;
+- ``prefill_flops(m, batch, prompt)`` and
+  ``decode_roofline_s(m, peaks, batch, pos)``: the yardstick of the
+  ``*_mfu`` metrics;
+- ``Reference(m)``, whose ``gaps`` decides ``correct``.
 
-It runs layer by layer: each layer's weights are made from the seed
-(``chipbench.weights``) just before use and dropped after, so the
-reference fits on a chip beside nothing else.  It imports nothing of
-the program and takes nothing the program made.
+Weights.  Seeded leaves named and shaped after the published
+configuration (``weights``): per layer the norms and projections, and
+the embedding, final norm and untied head; ``to_program`` maps them
+onto the program's tree.
+
+Counts.  Every matmul of every token (2 operations per multiply-add),
+causal attention as each query against the keys at or before it, and
+the head only where a token is sampled.  Not counted: norms, rotary
+embeddings, softmax, activations, masking and any work a program does
+beyond this (attention over masked or empty slots).  So a program's
+time can never be shorter than these counts at the chip's peaks.
+
+Reference.  Plain float32, straight from the published architecture:
+token embedding; per layer ``x += Wo . softmax(causal(q k^T / sqrt(hd))) v``
+on the RMS-normed input, with rotary embeddings (rotate-half form, base
+``rope_theta``) on q and k, optional q/k/v biases, and kv head
+``h // (H / KV)`` serving query head ``h``; then
+``x += W_down (silu(W_gate x) * W_up x)`` on the RMS-normed result; a
+final RMS norm; logits against the head (the embedding when tied).
+Every matmul runs at ``Precision.HIGHEST``, so float32 is float32 on a
+TPU too.  No cache, no chunking, no batching beyond a map over
+sequences.  It runs layer by layer: each layer's weights are made from
+the seed just before use and dropped after, so the reference fits on a
+chip beside nothing else.  It imports nothing of the program and takes
+nothing the program made (only ``program_config`` and ``make_params``
+import the program).
 
 The control is the same computation with every matrix (projections,
 embedding, head) rounded to a lower precision with one scale per output
@@ -24,12 +48,219 @@ channel: ``fp8`` (4 exponent and 3 mantissa bits, as float8 e4m3) or
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 
+from chipbench import model
 from chipbench import weights as W
+
+
+# -- sizes --------------------------------------------------------------
+
+def dims(c: dict) -> dict:
+    """The sizes the weights and the reference need, from a config file."""
+    d, heads = c["hidden_size"], c["num_attention_heads"]
+    return {"d": d, "f": c["intermediate_size"],
+            "layers": c["num_hidden_layers"], "heads": heads,
+            "kv_heads": c["num_key_value_heads"], "head_dim": d // heads,
+            "vocab": c["vocab_size"], "theta": float(c["rope_theta"]),
+            "eps": float(c["rms_norm_eps"]),
+            "tied": bool(c["tie_word_embeddings"]),
+            "qkv_bias": bool(c["qkv_bias"])}
+
+
+# -- weights ------------------------------------------------------------
+
+def layer_shapes(m: dict) -> dict:
+    """name -> (shape, dtype, init, scale) for one decoder layer."""
+    d, f = m["d"], m["f"]
+    q, kv = m["heads"] * m["head_dim"], m["kv_heads"] * m["head_dim"]
+    bf, span = jnp.bfloat16, W.NORM_SPAN
+    s = {"attn_norm": ((d,), jnp.float32, "norm", span),
+         "wq": ((d, q), bf, "normal", d ** -0.5),
+         "wk": ((d, kv), bf, "normal", d ** -0.5),
+         "wv": ((d, kv), bf, "normal", d ** -0.5),
+         "wo": ((q, d), bf, "normal", q ** -0.5),
+         "ffn_norm": ((d,), jnp.float32, "norm", span),
+         "w_gate": ((d, f), bf, "normal", d ** -0.5),
+         "w_up": ((d, f), bf, "normal", d ** -0.5),
+         "w_down": ((f, d), bf, "normal", f ** -0.5)}
+    if m["qkv_bias"]:
+        s.update(bq=((q,), bf, "normal", W.BIAS_STD),
+                 bk=((kv,), bf, "normal", W.BIAS_STD),
+                 bv=((kv,), bf, "normal", W.BIAS_STD))
+    return s
+
+
+def global_shapes(m: dict) -> dict:
+    s = {"embed": ((m["vocab"], m["d"]), jnp.bfloat16, "normal", 0.02),
+         "final_norm": ((m["d"],), jnp.float32, "norm", W.NORM_SPAN)}
+    if not m["tied"]:
+        s["lm_head"] = ((m["vocab"], m["d"]), jnp.bfloat16, "normal", 0.02)
+    return s
+
+
+def make_layer(m: dict, key, layer) -> dict:
+    """Layer ``layer``'s leaves in their served dtypes (traceable in
+    ``layer``)."""
+    return W.make(jax.random.fold_in(key, layer + 1), layer_shapes(m))
+
+
+def make_globals(m: dict, key) -> dict:
+    return W.make(jax.random.fold_in(key, 0), global_shapes(m))
+
+
+def make_all(m: dict, key) -> dict:
+    """Every leaf; layers stacked on a leading axis (one program)."""
+    layers = jax.vmap(lambda l: make_layer(m, key, l))(
+        jnp.arange(m["layers"], dtype=jnp.uint32))
+    return {"globals": make_globals(m, key), "layers": layers}
+
+
+def weight_bytes(m: dict) -> int:
+    """Bytes of every leaf as served."""
+    return m["layers"] * W.nbytes(layer_shapes(m)) + \
+        W.nbytes(global_shapes(m))
+
+
+# -- the program ----------------------------------------------------------
+
+# published config key -> the program's ModelConfig field
+_FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
+           "num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
+           "tie_word_embeddings": "tie_embeddings"}
+_ATTN = {"num_attention_heads": "num_heads",
+         "num_key_value_heads": "num_kv_heads", "rope_theta": "rope_theta",
+         "qkv_bias": "qkv_bias"}
+
+
+def program_config(c: dict):
+    """``repro.configs.get_config`` of the file's ``program.arch``, with
+    every field the file states (the published keys of ``_FIELDS`` and
+    ``_ATTN``, and the head size they imply) set to the file's value;
+    raises where the rest of the architecture differs from the file's.
+    The fields that differ from the registry are under
+    ``program.differs`` in the file, with why."""
+    from repro.configs import get_config
+    cfg = get_config(c["program"]["arch"])
+    cfg = dataclasses.replace(
+        cfg, attention=dataclasses.replace(
+            cfg.attention, head_dim=dims(c)["head_dim"],
+            **{f: c[k] for k, f in _ATTN.items()}),
+        **{f: c[k] for k, f in _FIELDS.items()})
+    a = cfg.attention
+    bad = {}
+    if cfg.family != "dense" or cfg.activation != "swiglu" \
+            or a.sliding_window or a.qk_norm or cfg.dtype != "bfloat16":
+        bad["architecture"] = (cfg.family, cfg.activation,
+                               a.sliding_window, a.qk_norm, cfg.dtype)
+    if bad:
+        raise ValueError(f"program config {cfg.name} differs from "
+                         f"{c['name']}: (program, file) {bad}")
+    return cfg
+
+
+def to_program(cfg, m: dict, w: dict) -> dict:
+    """Map the benchmark's leaves onto the program's parameter tree."""
+    g, L = w["globals"], w["layers"]
+    n, H, KV, hd = m["layers"], m["heads"], m["kv_heads"], m["head_dim"]
+    attn = {"wq": L["wq"].reshape(n, m["d"], H, hd),
+            "wk": L["wk"].reshape(n, m["d"], KV, hd),
+            "wv": L["wv"].reshape(n, m["d"], KV, hd),
+            "wo": L["wo"].reshape(n, H, hd, m["d"])}
+    if m["qkv_bias"]:
+        attn.update(bq=L["bq"].reshape(n, H, hd),
+                    bk=L["bk"].reshape(n, KV, hd),
+                    bv=L["bv"].reshape(n, KV, hd))
+    p = {"embed": model.pad_vocab(g["embed"], cfg.padded_vocab),
+         "final_norm": g["final_norm"],
+         "stage0": {"pos0": {
+             "ln_attn": L["attn_norm"], "attn": attn,
+             "ln_ffn": L["ffn_norm"],
+             "ffn": {"w_gate": L["w_gate"], "w_up": L["w_up"],
+                     "w_down": L["w_down"]}}}}
+    if not m["tied"]:
+        p["lm_head"] = model.pad_vocab(g["lm_head"], cfg.padded_vocab)
+    return p
+
+
+def make_params(cfg, m: dict, seed: int):
+    """The program's parameters from ``seed``, made on the device in one
+    jitted call, in the dtypes they are served in."""
+    return model.seeded_params(
+        cfg, lambda key: to_program(cfg, m, make_all(m, key)), seed)
+
+
+# -- counts -------------------------------------------------------------
+
+def layer_matmul_params(m: dict) -> int:
+    d, f = m["d"], m["f"]
+    q, kv = m["heads"] * m["head_dim"], m["kv_heads"] * m["head_dim"]
+    return d * q + 2 * d * kv + q * d + 3 * d * f
+
+
+def _attn_flops(m: dict, keys: int) -> int:
+    """One query against ``keys`` keys in every layer: q.k and p.v."""
+    return m["layers"] * 4 * m["heads"] * m["head_dim"] * keys
+
+
+def _head_flops(m: dict) -> int:
+    return 2 * m["d"] * m["vocab"]
+
+
+def prefill_flops(m: dict, batch: int, prompt: int) -> int:
+    """A prompt of ``prompt`` tokens per row; logits at the last one."""
+    per_row = (prompt * m["layers"] * 2 * layer_matmul_params(m)
+               + _attn_flops(m, prompt * (prompt + 1) // 2)
+               + _head_flops(m))
+    return batch * per_row
+
+
+def decode_flops(m: dict, batch: int, pos: int) -> int:
+    """One decode step writing position ``pos`` (it attends to pos + 1
+    keys) for ``batch`` rows."""
+    return batch * (m["layers"] * 2 * layer_matmul_params(m)
+                    + _attn_flops(m, pos + 1) + _head_flops(m))
+
+
+def kv_bytes_per_token(m: dict) -> int:
+    """bf16 keys and values of one token in every layer."""
+    return m["layers"] * 2 * m["kv_heads"] * m["head_dim"] * 2
+
+
+def weight_read_bytes(m: dict, batch: int) -> int:
+    """Weights one step reads: every layer once (bf16 matrices and
+    biases, f32 gains), the head once, and the embedding rows of the
+    batch where the head is not the embedding."""
+    d, n = m["d"], m["layers"]
+    q, kv = m["heads"] * m["head_dim"], m["kv_heads"] * m["head_dim"]
+    layer = 2 * layer_matmul_params(m) + 4 * 2 * d
+    if m["qkv_bias"]:
+        layer += 2 * (q + 2 * kv)
+    head = 2 * m["vocab"] * d + 4 * d
+    rows = 0 if m["tied"] else 2 * batch * d
+    return n * layer + head + rows
+
+
+def decode_bytes(m: dict, batch: int, pos: int) -> int:
+    """One decode step at position ``pos``: the weights once, the keys
+    and values of the ``pos`` filled positions, the new token's keys and
+    values, and the float32 logits."""
+    kv = kv_bytes_per_token(m)
+    return (weight_read_bytes(m, batch) + batch * pos * kv + batch * kv
+            + batch * m["vocab"] * 4)
+
+
+def decode_roofline_s(m: dict, peaks: dict, batch: int, pos: int) -> float:
+    """The least time one decode step can take on a chip with ``peaks``."""
+    return max(decode_flops(m, batch, pos) / peaks["bf16_flops"],
+               decode_bytes(m, batch, pos) / peaks["hbm_bytes_per_s"])
+
+
+# -- the reference --------------------------------------------------------
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -134,8 +365,8 @@ class Reference:
 
     def __init__(self, m: dict):
         self.m = m
-        self._layer = jax.jit(lambda l, key: _f32(W.make_layer(m, key, l)))
-        self._globals = jax.jit(lambda key: W.make_globals(m, key))
+        self._layer = jax.jit(lambda l, key: _f32(make_layer(m, key, l)))
+        self._globals = jax.jit(lambda key: make_globals(m, key))
         self._lower = jax.jit(lower, static_argnums=1)
         self._run_layer = jax.jit(
             lambda w, xs: jax.lax.map(functools.partial(layer, m, w), xs))

@@ -69,7 +69,9 @@ def test_blocks_sum_to_the_step_programs_device_time():
     dec = red["steps"]["decode_step"]["blocks"]
     assert dec["ffn"] == pytest.approx(400 / 2 * 1e-9)
     assert dec["head"] == pytest.approx(160 / 2 * 1e-9)
-    assert dec["attn_proj"] == dec["attn_core"] == dec["other"] == 0
+    # every block the map names is read, with no time where it has none
+    assert set(dec) == {"ffn", "head", "attn_core", "other"}
+    assert dec["attn_core"] == dec["other"] == 0
 
 
 def test_an_operation_no_map_knows_goes_to_other():
@@ -94,7 +96,7 @@ def test_decode_dispatch_is_read_on_the_hosts_clock():
 def test_readings_per_step_and_none_on_a_count_mismatch():
     red = blocks.reduce(EVENTS, MAPS)
     ms = blocks.per_step_ms(red, "decode_step", 2)
-    assert set(ms) == set(blocks.BLOCKS) | {blocks.OTHER}
+    assert set(ms) == set(MAPS["decode_step"].values()) | {blocks.OTHER}
     assert ms["ffn"] == pytest.approx(1e3 * 200e-9 / 2)
     assert blocks.per_step_ms(red, "prefill", 1)["attn_core"] == \
         pytest.approx(1e3 * 75e-9)
@@ -137,8 +139,54 @@ def test_dispatch_spans_leave_tracing_and_its_metrics_unchanged():
 
 def test_block_names_are_the_programs():
     from repro.obs import BLOCKS
-    assert blocks.BLOCKS == BLOCKS
     assert set(blocks.MATMUL) == set(BLOCKS) - {"attn_core"}
+    assert blocks.OTHER not in BLOCKS
+
+
+def test_a_block_no_one_named_before_is_summed_under_its_name():
+    """A block a later program adds (a state-space layer's ``ssm``) is
+    read under its own name, not raised on and not put in ``other``."""
+    maps = {k: dict(v) for k, v in MAPS.items()}
+    maps["decode_step"]["fusion.2"] = "ssm"
+    red = blocks.reduce(EVENTS, maps)
+    dec = red["steps"]["decode_step"]
+    assert dec["blocks"]["ssm"] == pytest.approx(400 / 2 * 1e-9)
+    assert dec["blocks"]["other"] == 0 and "fusion.2" not in dec["other_ops"]
+    assert sum(dec["blocks"].values()) == pytest.approx(dec["busy_s"])
+    assert blocks.per_step_ms(red, "decode_step", 2)["ssm"] == \
+        pytest.approx(1e3 * 200e-9 / 2)
+
+
+@pytest.mark.parametrize("program,served", [("decode_step", (1, [8, 9])),
+                                            ("prefill", (1, [8, 9]))])
+def test_block_metrics_sum_to_the_step_programs_device_time(program,
+                                                             served):
+    """The three block metrics of a step program read its ms per traced
+    step by block from a run's record, and together they are the
+    program's device time as ``tracing.reduce`` gives it."""
+    from chipbench import catalog
+    from chipbench.tests.test_tracing import _record
+    r = _record(tracing.reduce(EVENTS), *served)
+    r.blocks = blocks.reduce(EVENTS, dict(MAPS, prefill={
+        "fusion.1": "attn_core", "copy.7": "ffn"}))
+    n = len(r.traced_positions) if program == "decode_step" \
+        else r.traced_prefills
+    prefix = "decode" if program == "decode_step" else "prefill"
+    got = {b: catalog.reader(f"{prefix}_{b}_ms")(r)
+           for b in ("attn_core", "matmul", "other")}
+    ms = blocks.per_step_ms(r.blocks, program, n)
+    assert got["attn_core"] == ms["attn_core"]
+    assert got["other"] == ms["other"]
+    assert got["matmul"] == pytest.approx(sum(ms.get(b, 0.0)
+                                              for b in blocks.MATMUL))
+    busy_ms = 1e3 * r.trace["steps"][program]["busy_s"] / n
+    assert sum(got.values()) == pytest.approx(busy_ms, rel=1e-9)
+    # a count the trace does not match, or no reduction: no reading
+    r.traced_prefills, r.traced_positions = 2, [8, 9, 10]
+    assert all(catalog.reader(f"{prefix}_{b}_ms")(r) is None
+               for b in ("attn_core", "matmul", "other"))
+    r.blocks = None
+    assert catalog.reader(f"{prefix}_other_ms")(r) is None
 
 
 def test_maps_need_the_compiled_programs():

@@ -26,7 +26,9 @@ def test_fp8_control_fails_where_the_program_passes(name, seed):
     limits = [json.loads((LIMITS / f"{w}.json").read_text())["token_gap"]
               for w in STANDS_FOR[name]]
     # held to the loosest of the limits: the control must fail them all
-    one = catalog.Cell(name=name, chips=1, config=c, traffic=TRAFFIC,
+    one = catalog.Cell(name=name, chips=1, config=c,
+                       arch=catalog.architecture(c, DATA / f"{name}.json"),
+                       traffic=TRAFFIC,
                        limits={"token_gap": max(limits)}, end_to_end=[],
                        per_layer=[])
     out = cell.run_cell(one, seed, 0.5, False, time.monotonic(),

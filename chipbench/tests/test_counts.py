@@ -1,18 +1,17 @@
-"""The yardstick's operation and byte counts against hand counts, for
-both configurations."""
+"""The yardstick's operation and byte counts (``reference/dense_gqa.py``)
+against hand counts, for both configurations."""
 import json
 import pathlib
 
 import pytest
 
-from chipbench import counts
-from chipbench import weights as W
+from chipbench.reference import dense_gqa as counts
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
 
 
 def dims(name):
-    return W.dims(json.loads((CONFIGS / f"{name}.json").read_text()))
+    return counts.dims(json.loads((CONFIGS / f"{name}.json").read_text()))
 
 
 def test_qwen2_parameter_count_is_the_published_one():
@@ -32,7 +31,7 @@ def test_deepseek_slice_weight_bytes():
     # four layers (bf16 matrices, f32 gains), embedding + head, final gain
     want = 4 * (692_060_160 * 2 + 2 * 8192 * 4) + 2 * 838_860_800 * 2 \
         + 8192 * 4
-    assert W.weight_bytes(m) == want == 8_892_219_392
+    assert counts.weight_bytes(m) == want == 8_892_219_392
 
 
 @pytest.mark.parametrize("name,batch,prompt,want", [

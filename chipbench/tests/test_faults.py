@@ -58,7 +58,9 @@ def run(name, fault, seed):
     traffic = json.loads((DATA / "tiny-traffic.json").read_text())
     limit = min(json.loads((LIMITS / f"{w}.json").read_text())["token_gap"]
                 for w in STANDS_FOR[name])
-    one = catalog.Cell(name=name, chips=1, config=c, traffic=traffic,
+    one = catalog.Cell(name=name, chips=1, config=c,
+                       arch=catalog.architecture(c, DATA / f"{name}.json"),
+                       traffic=traffic,
                        limits={"token_gap": limit}, end_to_end=[],
                        per_layer=[])
     hook = None if fault is None else \

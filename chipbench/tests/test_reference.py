@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import model
 from chipbench import weights as W
 from chipbench.reference import dense_gqa as R
 
@@ -25,22 +24,22 @@ def config(name):
 def reference_logits(m, seed, tokens):
     """Logits at every position of ``tokens`` [T], layer by layer."""
     key = W.base_key(seed)
-    g = jax.tree.map(lambda a: a.astype(jnp.float32), W.make_globals(m, key))
+    g = jax.tree.map(lambda a: a.astype(jnp.float32), R.make_globals(m, key))
     x = jnp.take(g["embed"], tokens, 0)
     for li in range(m["layers"]):
         w = jax.tree.map(lambda a: a.astype(jnp.float32),
-                         W.make_layer(m, key, jnp.uint32(li)))
+                         R.make_layer(m, key, jnp.uint32(li)))
         x = R.layer(m, w, x)
     return R._logits(m, g, R._head(m, g, None), x)
 
 
 @pytest.mark.parametrize("name", ["tiny-qwen2", "tiny-llama"])
 def test_layers_made_alone_equal_the_stacked_whole(name):
-    m = W.dims(config(name))
+    m = R.dims(config(name))
     key = W.base_key(SEED)
-    whole = jax.jit(lambda k: W.make_all(m, k))(key)
+    whole = jax.jit(lambda k: R.make_all(m, k))(key)
     for li in range(m["layers"]):
-        alone = jax.jit(lambda k, l: W.make_layer(m, k, l))(key,
+        alone = jax.jit(lambda k, l: R.make_layer(m, k, l))(key,
                                                            jnp.uint32(li))
         for n, a in alone.items():
             np.testing.assert_array_equal(np.asarray(a),
@@ -53,10 +52,10 @@ def test_reference_matches_program_prefill_and_decode_in_float32(name):
     from repro.models import lm
     from repro.models.lm import RunOptions
     c = config(name)
-    m = W.dims(c)
-    cfg = model.program_config(c)
+    m = R.dims(c)
+    cfg = R.program_config(c)
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          model.make_params(cfg, m, SEED))
+                          R.make_params(cfg, m, SEED))
     cfg32 = dataclasses.replace(cfg, dtype="float32")
     P, G = 24, 6
     toks = np.random.default_rng(0).integers(0, m["vocab"], (2, P + G),
@@ -77,7 +76,7 @@ def test_reference_matches_program_prefill_and_decode_in_float32(name):
 
 
 def test_gaps_are_zero_for_the_reference_own_tokens():
-    m = W.dims(config("tiny-llama"))
+    m = R.dims(config("tiny-llama"))
     toks = np.random.default_rng(1).integers(0, m["vocab"], (20,),
                                              dtype=np.int32)
     # greedy tokens of the reference itself, teacher-forced

@@ -64,9 +64,11 @@ def test_union_of_overlapping_and_nested_intervals():
 
 def _record(trace, prefills, positions, m=None):
     from chipbench.record import Record
+    from chipbench.reference import dense_gqa
     m = m or {"d": 64, "f": 160, "layers": 2, "heads": 4, "kv_heads": 2,
               "head_dim": 16, "vocab": 512, "tied": True, "qkv_bias": True}
-    r = Record(dims=m, peaks={"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9},
+    r = Record(arch=dense_gqa, dims=m,
+               peaks={"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9},
                batch=2, prompt_len=8, setup_s=1.0)
     r.trace, r.traced_prefills, r.traced_positions = trace, prefills, \
         positions
@@ -74,7 +76,8 @@ def _record(trace, prefills, positions, m=None):
 
 
 def test_mfu_readers_divide_by_the_step_programs_device_time():
-    from chipbench import catalog, counts
+    from chipbench import catalog
+    from chipbench.reference import dense_gqa as counts
     trace = {"busy_s": 1.0, "window_s": 2.0,
              "steps": {"prefill": {"n": 2, "busy_s": 0.5},
                        "decode_step": {"n": 3, "busy_s": 0.25}}}

@@ -4,17 +4,18 @@ launch, from a traced segment like the one ``run.py --trace 1`` takes.
     python3 chipbench/tools/breakdown.py --workload qwen2-0.5b.decode \
         --seeds 7,8 [--seconds 10] [--out chiprun_out/breakdown.jsonl]
 
-Per seed: sets the cell up as a run does (``cell.build_server``, the
-same warm-up), serves ``--seconds`` untraced, then ``cell.TRACE_S``
-under the profiler, and reduces that trace twice: ``tracing.reduce``
-(what the benchmark's per-layer metrics read) and ``blocks.reduce``.
-One JSON line per seed: per step program the ms per step of each block
-and ``other``, their sum against the program's device time, the share
-of that time in operations with no ``op_name`` at all, and the
-``other`` operations that took most; the median and largest decode
-launch; and the median and slowest step times in the window and in the
-traced segment (what tracing costs).  There is no reference check:
-``correct`` is ``run.py``'s.  Needs the cell's TPU chips, like a run.
+Per seed: sets the cell up as a run does (``cell.set_up``), serves
+``--seconds`` untraced, then the run's traced segment
+(``cell.traced_segment``: ``cell.TRACE_S`` under the profiler, reduced
+by ``tracing.reduce`` and ``blocks.reduce`` into the record the
+per-layer metrics read).  One JSON line per seed: per step program
+the ms per step of each block and ``other``, their sum against the
+program's device time, the share of that time in operations with no
+``op_name`` at all, and the ``other`` operations that took most; the
+median and largest decode launch; and the median and slowest step times
+in the window and in the traced segment (what tracing costs).  There is
+no reference check: ``correct`` is ``run.py``'s.  Needs the cell's TPU
+chips, like a run.
 """
 from __future__ import annotations
 
@@ -25,10 +26,7 @@ import os
 import pathlib
 import statistics
 import sys
-import tempfile
 import time
-
-import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
@@ -40,50 +38,33 @@ def step_gaps(batches, lo: float, hi: float) -> list:
 
 
 def one_seed(cell, seed: int, seconds: float) -> dict:
-    import jax
-    from chipbench import blocks, loop, model, tracing
+    from chipbench import blocks, loop
     from chipbench import cell as C
-    from chipbench import weights as W
+    from chipbench.peaks import peaks_for
+    from chipbench.record import Record
     from repro.obs import op_names
 
-    C.devices(cell.chips)
-    traffic = cell.traffic
-    m = W.dims(cell.config)
-    server = C.build_server(model.program_config(cell.config), m, traffic,
-                            seed)
-    warm = loop.Batch(server, np.zeros((traffic["batch"],
-                                        traffic["prompt_len"]), np.int32))
-    warm.start()
-    warm.step()
-    warm.step()
-    del warm
-    prompts = C.prompt_source(traffic, m["vocab"], seed)
+    dev = C.devices(cell.chips)[0]
+    server, m, prompts = C.set_up(cell, seed)
     gc.collect()
     gc.freeze()
     t0 = time.monotonic()
     batches: list = []
     inflight = loop.serve(server, prompts, t0 + seconds, log=batches)
-    going = inflight if inflight is not None and not inflight.done else None
-    k0, n0 = (going.steps if going else 0), len(batches)
-    with tempfile.TemporaryDirectory(prefix="chipbench_trace_") as d:
-        jax.profiler.start_trace(d)
-        t1 = time.monotonic()
-        with loop.annotate(tracing.WINDOW):
-            loop.serve(server, prompts, t1 + C.TRACE_S, inflight, batches)
-        t2 = time.monotonic()
-        jax.profiler.stop_trace()
-        trace = tracing.reduce(tracing.load(d))
-        events = blocks.load(d)
+    rec = Record(arch=cell.arch, dims=m, peaks=peaks_for(dev.device_kind),
+                 batch=cell.traffic["batch"],
+                 prompt_len=cell.traffic["prompt_len"], setup_s=0.0)
+    t1 = time.monotonic()
+    C.traced_segment(server, prompts, inflight, batches, rec)
+    t2 = time.monotonic()
     gc.unfreeze()
-    served = {"prefill": len(batches) - n0,
-              "decode_step": (going.steps - k0 if going else 0)
-              + sum(b.steps for b in batches[n0:])}
-    maps = blocks.maps(server)
-    red = blocks.reduce(events, maps)
+    trace, red = rec.trace, rec.blocks
+    served = {"prefill": rec.traced_prefills,
+              "decode_step": len(rec.traced_positions)}
     out = {"workload": cell.name, "seed": seed, "served": served}
     for k, attr in blocks.PROGRAMS.items():
         ms = blocks.per_step_ms(red, k, served[k])
-        if ms is None or not trace or not served[k]:
+        if ms is None or not trace:
             continue
         s = red["steps"][k]
         busy_ms = 1e3 * trace["steps"][k]["busy_s"] / served[k]
@@ -92,8 +73,8 @@ def one_seed(cell, seed: int, seconds: float) -> dict:
                        if name not in named)
         out[k] = {
             "ms_per_step": ms,
-            "attn_core": ms["attn_core"],
-            "matmul": sum(ms[b] for b in blocks.MATMUL),
+            "attn_core": ms.get("attn_core"),
+            "matmul": sum(ms.get(b, 0.0) for b in blocks.MATMUL),
             "other": ms[blocks.OTHER],
             "blocks_sum_over_busy": sum(ms.values()) / busy_ms,
             "busy_ms_per_step": busy_ms,
