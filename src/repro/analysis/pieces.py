@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
+from repro.models import attention as attn_mod
 from repro.models import blocks as blk
 from repro.models import lm as lm_mod
 from repro.models.lm import RunOptions
@@ -92,7 +93,7 @@ def _unit_fn(cfg, stage, opts, *, train: bool, collect: bool,
 
     def fwd(up, x, x0, shared, memory):
         out, aux, cache = lm_mod._apply_unit_full(
-            cfg, up, stage.unit, x, x0, _positions(x.shape[1]), opts,
+            cfg, up, stage, x, x0, _positions(x.shape[1]), opts,
             collect, memory, shared, jnp.zeros((), jnp.int32), x.shape[1])
         loss = out.astype(jnp.float32).sum() + aux
         return (loss, cache) if collect else loss
@@ -128,21 +129,27 @@ def _chunked_stage_pieces(cfg, stage, si, B, S, rules, opts, train,
     n_shared_apps = sum(1 for d in stage.unit if d.shared_attn) \
         * stage.n_units
     if n_shared_apps:
-        def shared_fn(shared, x, x0):
-            def fwd(shared, x, x0):
+        a = cfg.attention
+
+        def shared_fn(shared, hp, x, x0):
+            def fwd(shared, hp, x, x0):
                 sp = blk.tree_index(shared, 0)
-                out, _ = lm_mod._shared_block_full(
-                    cfg, sp, x, x0, _positions(x.shape[1]),
-                    RunOptions(chunk_q=0, chunk_kv=0,
-                               shardings=opts.shardings), False)
+                att = attn_mod.self_attention(
+                    sp["attn"], lm_mod._hybrid_in(cfg, sp, x, x0), a,
+                    _positions(x.shape[1]), theta=a.rope_theta, window=0,
+                    chunk_q=0, chunk_kv=0)
+                out = lm_mod._hybrid_out(cfg, sp, hp, att)
                 return out.astype(jnp.float32).sum()
             if train:
-                return jax.grad(fwd, argnums=(0, 1))(shared, x, x0)
-            return fwd(shared, x, x0)
+                return jax.grad(fwd, argnums=(0, 1, 2))(shared, hp, x, x0)
+            return fwd(shared, hp, x, x0)
+        hybrid = next(d for d in stage.unit if d.shared_attn)
+        own = {k: v for k, v in blk.layer_spec(cfg, hybrid).items()
+               if k in ("adapter_a", "adapter_b", "linear")}
         pieces.append(Piece(
             f"stage{si}_shared_attn", n_shared_apps, shared_fn,
-            (_shared_specs(cfg, rules), _x_spec(cfg, B, S, rules),
-             _x_spec(cfg, B, S, rules))))
+            (_shared_specs(cfg, rules), shape_tree(own, rules),
+             _x_spec(cfg, B, S, rules), _x_spec(cfg, B, S, rules))))
 
     stage1 = blk.StageDescr(1, _strip_shared_attn(
         blk.StageDescr(1, (stage.unit[-1],))).unit)
@@ -293,7 +300,7 @@ def decode_pieces(cfg, shape, rules, opts) -> List[Piece]:
         def mk(stage_, has_shared_):
             def fn(up, cache_u, x, x0, shared=None):
                 out, nc = lm_mod._apply_unit_decode(
-                    cfg, up, stage_.unit, x, x0, jnp.int32(cache_len - 1),
+                    cfg, up, stage_, x, x0, jnp.int32(cache_len - 1),
                     popts, cache_u, shared, jnp.zeros((), jnp.int32))
                 return out, nc
             return fn

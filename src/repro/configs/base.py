@@ -97,9 +97,16 @@ class SSMConfig:
     expand: int = 2
     conv_kernel: int = 4
     chunk_size: int = 256
-    # zamba2: a weight-tied attention block applied every N ssm layers
-    shared_attn_every: int = 0
-    n_shared_blocks: int = 2  # alternating tied blocks (zamba2 uses 2)
+    # B/C groups: head h reads group h // (heads / n_groups), and the
+    # gated norm normalises each group's d_inner / n_groups channels
+    n_groups: int = 1
+    # zamba2: the layers (by id) that first run one of n_shared_blocks
+    # weight-tied transformer blocks, chosen by the hybrid layer's
+    # ordinal among them mod n_shared_blocks; each hybrid layer adds its
+    # own rank-adapter_rank LoRA to the block's gate/up projection
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_shared_blocks: int = 2
+    adapter_rank: int = 0
 
 
 @dataclass(frozen=True)
@@ -146,7 +153,7 @@ class ModelConfig:
     rwkv: Optional[RWKVConfig] = None
     encdec: Optional[EncDecConfig] = None
     frontend: FrontendStub = field(default_factory=FrontendStub)
-    activation: str = "swiglu"  # swiglu | geglu | gelu
+    activation: str = "swiglu"  # swiglu | geglu | geglu_exact | gelu
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     scale_embeddings: bool = False  # gemma: embeddings * sqrt(d_model)

@@ -8,13 +8,15 @@ supporting heterogeneous patterns:
 
   gemma3    : 1 stage, 8 units  x [L,L,L,L,L,G] attention layers
   llama4    : 1 stage, 24 units x [moe_layer, dense_layer]
-  zamba2    : stage0: 13 units x [shared_attn+mamba, mamba x5],
-              stage1: 1 unit   x [mamba x3]     (81 = 13*6 + 3)
+  zamba2    : the layers grouped at its hybrid ids (6, 11, 17, ..., 77):
+              6 units x [mamba], 1 x [hybrid, mamba x4],
+              11 x [hybrid, mamba x5], 1 x [hybrid, mamba x3]
   others    : 1 stage, n_layers units x [layer]
 
 Static per-position metadata (window size, rope theta, moe flag) is
 baked into the traced program; per-unit dynamic metadata (the unit
-index, for zamba2's alternating tied blocks) is scanned over.
+index, which also picks zamba2's alternating tied block in a stage of
+several hybrid units) is scanned over.
 """
 from __future__ import annotations
 
@@ -43,7 +45,7 @@ class LayerDescr:
     window: int = 0            # 0 = global
     theta: float = 10_000.0
     use_moe: bool = False
-    shared_attn: bool = False  # zamba2: tied attn block applied first
+    shared_attn: bool = False  # zamba2 hybrid layer: tied block first
     causal: bool = True
 
 
@@ -51,10 +53,43 @@ class LayerDescr:
 class StageDescr:
     n_units: int
     unit: Tuple[LayerDescr, ...]
+    hybrid0: int = 0   # ordinal among hybrid layers of the stage's first
 
     @property
     def unit_len(self) -> int:
         return len(self.unit)
+
+    def hybrid_ordinal(self, unit_idx, i: int):
+        """Ordinal among the model's hybrid layers of the hybrid layer
+        at position ``i`` of unit ``unit_idx`` (static where the stage
+        has one unit, else traced like ``unit_idx``)."""
+        per = sum(d.shared_attn for d in self.unit)
+        before = sum(d.shared_attn for d in self.unit[:i])
+        if self.n_units == 1:
+            return self.hybrid0 + before
+        return self.hybrid0 + unit_idx * per + before
+
+
+def _hybrid_stages(cfg: ModelConfig) -> Tuple[StageDescr, ...]:
+    """The Mamba layers before the first hybrid id as units of one
+    layer, then one unit per hybrid id: [hybrid, mamba x k] up to the
+    next id; neighbouring units of equal k share a stage.  Ids at or
+    past ``num_layers`` lie beyond this model's layers (a stage of a
+    deeper model)."""
+    ids = [i for i in cfg.ssm.hybrid_layer_ids if i < cfg.num_layers]
+    mamba, hybrid = LayerDescr("mamba"), LayerDescr("mamba",
+                                                    shared_attn=True)
+    lead = ids[0] if ids else cfg.num_layers
+    stages = [StageDescr(lead, (mamba,))] if lead else []
+    for k, start in enumerate(ids):
+        end = ids[k + 1] if k + 1 < len(ids) else cfg.num_layers
+        unit = (hybrid,) + (mamba,) * (end - start - 1)
+        last = stages[-1] if stages else None
+        if last is not None and last.unit == unit:
+            stages[-1] = StageDescr(last.n_units + 1, unit, last.hybrid0)
+        else:
+            stages.append(StageDescr(1, unit, k))
+    return tuple(stages)
 
 
 def build_stages(cfg: ModelConfig) -> Tuple[StageDescr, ...]:
@@ -83,17 +118,7 @@ def build_stages(cfg: ModelConfig) -> Tuple[StageDescr, ...]:
         return (StageDescr(cfg.num_layers // m.moe_every, unit),)
 
     if cfg.family == "hybrid":
-        s = cfg.ssm
-        per = s.shared_attn_every
-        n_full = cfg.num_layers // per
-        tail = cfg.num_layers - n_full * per
-        unit = tuple(
-            LayerDescr("mamba", shared_attn=(i == 0)) for i in range(per))
-        stages = [StageDescr(n_full, unit)]
-        if tail:
-            stages.append(StageDescr(
-                1, tuple(LayerDescr("mamba") for _ in range(tail))))
-        return tuple(stages)
+        return _hybrid_stages(cfg)
 
     if cfg.family == "rwkv":
         return (StageDescr(cfg.num_layers, (LayerDescr("rwkv"),)),)
@@ -142,10 +167,21 @@ def layer_spec(cfg: ModelConfig, dsc: LayerDescr) -> dict:
             "ffn": ffn_mod.dense_ffn_spec(d, cfg.d_ff, cfg.activation, dt),
         }
     if dsc.kind == "mamba":
-        return {
+        p = {
             "ln": rmsnorm_spec(d),
             "mamba": ssm_mod.mamba_spec(d, cfg.ssm, dt),
         }
+        if dsc.shared_attn:
+            # this hybrid layer's own: the LoRA on the tied block's
+            # gate/up projection, and the linear into the Mamba input
+            r = cfg.ssm.adapter_rank
+            p["adapter_a"] = Par((d, r), ("embed", None), init="scaled",
+                                 dtype=dt)
+            p["adapter_b"] = Par((r, 2 * cfg.d_ff), (None, "ffn"),
+                                 init="scaled", dtype=dt)
+            p["linear"] = Par((d, d), ("embed", None), init="scaled",
+                              dtype=dt)
+        return p
     if dsc.kind == "rwkv":
         return {
             "ln_tm": rmsnorm_spec(d),
@@ -157,7 +193,9 @@ def layer_spec(cfg: ModelConfig, dsc: LayerDescr) -> dict:
 
 
 def shared_block_spec(cfg: ModelConfig) -> dict:
-    """zamba2's weight-tied attention block operating on concat(x, x0)."""
+    """zamba2's weight-tied transformer block operating on
+    concat(x, x0): attention from 2 * d_model to d_model, then the
+    gated MLP (``lm._hybrid_in``, ``lm._hybrid_out``)."""
     d, dt = cfg.d_model, cfg.dtype
     return {
         "ln_in": rmsnorm_spec(2 * d),

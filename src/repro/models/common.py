@@ -33,6 +33,8 @@ def activate(h_gate: jax.Array, h_up: Optional[jax.Array],
         return jax.nn.silu(h_gate) * h_up
     if kind == "geglu":
         return jax.nn.gelu(h_gate, approximate=True) * h_up
+    if kind == "geglu_exact":
+        return jax.nn.gelu(h_gate, approximate=False) * h_up
     if kind == "gelu":
         return jax.nn.gelu(h_gate, approximate=True)
     if kind == "relu_sq":
@@ -41,7 +43,7 @@ def activate(h_gate: jax.Array, h_up: Optional[jax.Array],
 
 
 def is_gated(kind: str) -> bool:
-    return kind in ("swiglu", "geglu")
+    return kind in ("swiglu", "geglu", "geglu_exact")
 
 
 # ---------------------------------------------------------------------------

@@ -42,9 +42,18 @@ def dense_ffn_spec(d_model: int, d_ff: int, activation: str,
 
 
 @jax.named_scope(FFN)
-def dense_ffn(p: dict, x: jax.Array, activation: str) -> jax.Array:
+def dense_ffn(p: dict, x: jax.Array, activation: str,
+              adapter: Optional[Tuple[jax.Array, jax.Array]] = None
+              ) -> jax.Array:
+    """``adapter`` (A [d, r], B [r, 2 * d_ff]): a low-rank term
+    (x A) B added to the gate (first half) and up projections."""
     hg = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
     hu = jnp.einsum("bsd,df->bsf", x, p["w_up"]) if "w_up" in p else None
+    if adapter is not None:
+        lo = jnp.einsum("bsd,dr->bsr", x, adapter[0])
+        ad = jnp.einsum("bsr,rf->bsf", lo, adapter[1])
+        hg = hg + ad[..., :hg.shape[-1]]
+        hu = hu + ad[..., hg.shape[-1]:]
     h = activate(hg, hu, activation)
     return jnp.einsum("bsf,fd->bsd", h, p["w_down"])
 

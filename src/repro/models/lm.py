@@ -28,7 +28,7 @@ from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import rmsnorm, rmsnorm_spec
 from repro.models.spec import Par, init_tree, is_par, stack
-from repro.obs.blocks import ATTN_CORE, HEAD
+from repro.obs.blocks import ATTN_CORE, FFN, HEAD
 
 MAX_POS_TABLE = 32_768  # whisper learned-position tables
 
@@ -227,27 +227,39 @@ def _to_cache_buf(k: jax.Array, cache_len: int,
     return _wsc(buf, opts, "kv")
 
 
-def _shared_block_full(cfg, sp, x, x0, positions, opts, collect):
-    cat = jnp.concatenate([x, x0], axis=-1)
-    h = rmsnorm(cat, sp["ln_in"])
-    res = attn_mod.self_attention(
-        sp["attn"], h, cfg.attention, positions,
-        theta=cfg.attention.rope_theta, window=0, chunk_q=opts.chunk_q,
-        chunk_kv=opts.chunk_kv, return_kv=collect)
-    att, kv = res if collect else (res, None)
-    x = x + att
-    h2 = rmsnorm(x, sp["ln_ffn"])
-    x = x + ffn_mod.dense_ffn(sp["ffn"], h2, cfg.activation)
-    return x, kv
+def _hybrid_in(cfg: ModelConfig, sp: dict, x, x0):
+    """The tied block's attention input: RMSNorm of concat(x, x0)."""
+    return rmsnorm(jnp.concatenate([x, x0], axis=-1), sp["ln_in"],
+                   cfg.norm_eps)
 
 
-def _apply_unit_full(cfg: ModelConfig, up: dict, unit, x, x0, positions,
+def _hybrid_out(cfg: ModelConfig, sp: dict, p: dict, att):
+    """The rest of a zamba2 hybrid layer's tied block after attention:
+    the gated MLP on the normed attention output, with this layer's
+    LoRA on the gate/up projection, then this layer's linear.  The block
+    adds no residual: what it returns is added to the layer's Mamba
+    input, not to x."""
+    g = rmsnorm(att, sp["ln_ffn"], cfg.norm_eps)
+    f = ffn_mod.dense_ffn(sp["ffn"], g, cfg.activation,
+                          adapter=(p["adapter_a"], p["adapter_b"]))
+    with jax.named_scope(FFN):
+        return jnp.einsum("bsd,de->bse", f, p["linear"])
+
+
+def _shared_block(cfg: ModelConfig, stage, shared, unit_idx, i: int):
+    """The tied block of the hybrid layer at position ``i`` of unit
+    ``unit_idx``: its ordinal among hybrid layers mod the block count."""
+    sel = stage.hybrid_ordinal(unit_idx, i) % cfg.ssm.n_shared_blocks
+    return blk.tree_index(shared, sel)
+
+
+def _apply_unit_full(cfg: ModelConfig, up: dict, stage, x, x0, positions,
                      opts: RunOptions, collect: bool, memory, shared,
                      unit_idx, cache_len: int):
     aux = jnp.zeros((), jnp.float32)
     cache = {}
     a = cfg.attention
-    for i, dsc in enumerate(unit):
+    for i, dsc in enumerate(stage.unit):
         p = up[f"pos{i}"]
         c = {}
         if dsc.kind in ("attn", "enc_attn"):
@@ -297,21 +309,27 @@ def _apply_unit_full(cfg: ModelConfig, up: dict, unit, x, x0, positions,
                      "v": _to_cache_buf(kv[1], cache_len, opts),
                      "ck": ck, "cv": cv}
         elif dsc.kind == "mamba":
+            h = x
             if dsc.shared_attn:
-                sel = unit_idx % cfg.ssm.n_shared_blocks
-                sp = blk.tree_index(shared, sel)
-                x, skv = _shared_block_full(cfg, sp, x, x0, positions,
-                                            opts, collect)
+                sp = _shared_block(cfg, stage, shared, unit_idx, i)
+                res = attn_mod.self_attention(
+                    sp["attn"], _hybrid_in(cfg, sp, x, x0), a, positions,
+                    theta=a.rope_theta, window=0, chunk_q=opts.chunk_q,
+                    chunk_kv=opts.chunk_kv, return_kv=collect)
+                att, skv = res if collect else (res, None)
+                h = x + _hybrid_out(cfg, sp, p, att)
                 if collect:
                     c["shared_k"] = _to_cache_buf(skv[0], cache_len, opts)
                     c["shared_v"] = _to_cache_buf(skv[1], cache_len, opts)
-            h = rmsnorm(x, p["ln"])
+            h = rmsnorm(h, p["ln"], cfg.norm_eps)
             if collect:
                 m, st = ssm_mod.mamba_forward(p["mamba"], h, cfg.ssm,
-                                              None, return_state=True)
+                                              None, return_state=True,
+                                              eps=cfg.norm_eps)
                 c["conv"], c["ssm"] = st["conv"], st["ssm"]
             else:
-                m = ssm_mod.mamba_forward(p["mamba"], h, cfg.ssm)
+                m = ssm_mod.mamba_forward(p["mamba"], h, cfg.ssm,
+                                          eps=cfg.norm_eps)
             x = x + m
         elif dsc.kind == "rwkv":
             h = rmsnorm(x, p["ln_tm"])
@@ -357,7 +375,7 @@ def _run_stage_full(cfg, sp, stage: blk.StageDescr, x, x0, positions, opts,
         xx, au = carry
         up, ui = inp
         xx, d_aux, cache = _apply_unit_full(
-            cfg, up, stage.unit, xx, x0, positions, opts, collect, memory,
+            cfg, up, stage, xx, x0, positions, opts, collect, memory,
             shared, ui, cache_len)
         return (_wsc(xx, opts, "x"), au + d_aux), cache
 
@@ -424,7 +442,7 @@ def forward_hidden(cfg: ModelConfig, params: dict, batch: dict,
         aux = aux + a_i
         caches[f"stage{si}"] = c_i
     with jax.named_scope(HEAD):
-        x = rmsnorm(x, params["final_norm"])
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x, aux, (caches if collect else None)
 
 
@@ -454,12 +472,12 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict,
     return logits, caches
 
 
-def _apply_unit_decode(cfg: ModelConfig, up: dict, unit, x, x0, pos,
+def _apply_unit_decode(cfg: ModelConfig, up: dict, stage, x, x0, pos,
                        opts: RunOptions, cache_unit: dict, shared,
                        unit_idx):
     a = cfg.attention
     new_cache = {}
-    for i, dsc in enumerate(unit):
+    for i, dsc in enumerate(stage.unit):
         p = up[f"pos{i}"]
         c = cache_unit[f"pos{i}"]
         nc = {}
@@ -495,22 +513,20 @@ def _apply_unit_decode(cfg: ModelConfig, up: dict, unit, x, x0, pos,
             x = x + ffn_mod.dense_ffn(p["ffn"], h, cfg.activation)
             nc = {"k": nk, "v": nv, "ck": c["ck"], "cv": c["cv"]}
         elif dsc.kind == "mamba":
+            h = x
             if dsc.shared_attn:
-                sel = unit_idx % cfg.ssm.n_shared_blocks
-                sp = blk.tree_index(shared, sel)
-                cat = jnp.concatenate([x, x0], axis=-1)
-                h = rmsnorm(cat, sp["ln_in"])
+                sp = _shared_block(cfg, stage, shared, unit_idx, i)
                 att, sk, sv = attn_mod.decode_attention(
-                    sp["attn"], h, a, c["shared_k"], c["shared_v"],
-                    unit_idx, pos, theta=a.rope_theta, window=0)
-                x = x + att
-                h2 = rmsnorm(x, sp["ln_ffn"])
-                x = x + ffn_mod.dense_ffn(sp["ffn"], h2, cfg.activation)
+                    sp["attn"], _hybrid_in(cfg, sp, x, x0), a,
+                    c["shared_k"], c["shared_v"], unit_idx, pos,
+                    theta=a.rope_theta, window=0)
+                h = x + _hybrid_out(cfg, sp, p, att)
                 nc["shared_k"], nc["shared_v"] = sk, sv
-            h = rmsnorm(x, p["ln"])
+            h = rmsnorm(h, p["ln"], cfg.norm_eps)
             m, st = ssm_mod.mamba_decode(p["mamba"], h, cfg.ssm,
                                          {"conv": c["conv"],
-                                          "ssm": c["ssm"]})
+                                          "ssm": c["ssm"]},
+                                         eps=cfg.norm_eps)
             x = x + m
             nc["conv"], nc["ssm"] = st["conv"], st["ssm"]
         elif dsc.kind == "rwkv":
@@ -557,7 +573,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
             xx, kv = carry
             up, ui, cu = inp
             both = {u: {**cu[u], **kv[u]} for u in cu}
-            xx, nc = _apply_unit_decode(cfg, up, _st.unit, xx, x0, pos,
+            xx, nc = _apply_unit_decode(cfg, up, _st, xx, x0, pos,
                                         opts, both, shared, ui)
             kv, nc = _split_kv(cfg, _st, nc)
             return (xx, kv), nc
@@ -574,6 +590,6 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
             rest = jax.tree.map(lambda *xs: jnp.stack(xs), *ncl)
         new_caches[f"stage{si}"] = {u: {**rest[u], **kv[u]} for u in kv}
     with jax.named_scope(HEAD):
-        x = rmsnorm(x, params["final_norm"])
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = compute_logits(cfg, params, x[:, 0])
     return logits, new_caches

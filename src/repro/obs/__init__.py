@@ -21,7 +21,7 @@ package makes it observable:
   operations to them (what a device trace's operation time is summed
   by).
 """
-from repro.obs.blocks import BLOCKS, op_blocks, op_names
+from repro.obs.blocks import BLOCKS, SSM_BLOCKS, op_blocks, op_names
 from repro.obs.chrome_trace import to_chrome_trace, write_chrome_trace
 from repro.obs.jitter import JitterStats, jitter_stats, simulate_sweep
 from repro.obs.report import (BENCH_SCHEMA_VERSION, hw_fingerprint,
@@ -31,6 +31,7 @@ from repro.obs.trace import Counter, Instant, Span, TraceRecorder
 __all__ = [
     "BENCH_SCHEMA_VERSION",
     "BLOCKS",
+    "SSM_BLOCKS",
     "Counter",
     "Instant",
     "JitterStats",

@@ -9,7 +9,17 @@ The model code runs each block under ``jax.named_scope(<block>)``:
   cache writes (decode's two ``dynamic_update_slice``s, prefill's
   ``lm._to_cache_buf``);
 - ``ffn``: ``ffn.dense_ffn`` and ``ffn.moe_ffn``;
-- ``head``: the final norm and ``lm.compute_logits``.
+- ``head``: the final norm and ``lm.compute_logits``;
+
+and a state-space layer's (``SSM_BLOCKS``; ``models/ssm.py``):
+
+- ``ssm_proj``: its input and output projections;
+- ``ssm_core``: the causal convolution, the chunked scan or the decode
+  step's state update, the D skip and the grouped gated norm.
+
+``BLOCKS`` are the blocks every served transformer step program names;
+a program of a state-space model names ``SSM_BLOCKS`` besides.  A
+hybrid layer's per-layer adapter and linear are under ``ffn``.
 
 Everything else (embedding, the layer loop's slicing of stacked weights
 and caches, norms and residual adds that XLA does not fuse into a block,
@@ -27,6 +37,9 @@ import re
 
 BLOCKS = ("attn_proj", "attn_core", "ffn", "head")
 ATTN_PROJ, ATTN_CORE, FFN, HEAD = BLOCKS
+SSM_BLOCKS = ("ssm_proj", "ssm_core")
+SSM_PROJ, SSM_CORE = SSM_BLOCKS
+_NAMED = BLOCKS + SSM_BLOCKS
 
 # ``  %fusion.12 = bf16[8]{0} fusion(...), ..., metadata={op_name="..."``
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s+=\s.*?'
@@ -40,9 +53,10 @@ def op_names(hlo_text: str) -> dict[str, str]:
 
 
 def block_of(op_name: str) -> str | None:
-    """The innermost of ``BLOCKS`` on the scope path ``op_name``."""
+    """The innermost of ``BLOCKS`` and ``SSM_BLOCKS`` on the scope path
+    ``op_name``."""
     for part in reversed(op_name.split("/")):
-        if part in BLOCKS:
+        if part in _NAMED:
             return part
     return None
 

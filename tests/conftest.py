@@ -83,7 +83,8 @@ TINY_LAYERS = {
     "qwen2-72b": 2,
     "pixtral-12b": 2,
     "whisper-base": 2,
-    "zamba2-7b": 15,            # 2 units of [shared+5] + 3-layer tail
+    "zamba2-7b": 23,            # [6 M], [H, 4 M], 2 x [H, 5 M]: hybrid
+    #                             ids 6, 11, 17; blocks 0, 1, 0
     "llama4-maverick-400b-a17b": 4,
     "qwen3-moe-235b-a22b": 2,
     "rwkv6-1.6b": 2,

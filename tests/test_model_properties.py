@@ -113,8 +113,8 @@ def test_ssd_chunk_size_invariance():
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     x = jax.random.normal(ks[0], (B, S, H, P))
     a = -jnp.abs(jax.random.normal(ks[1], (B, S, H))) * 0.1
-    Bm = jax.random.normal(ks[2], (B, S, N))
-    Cm = jax.random.normal(ks[3], (B, S, N))
+    Bm = jax.random.normal(ks[2], (B, S, 1, N))   # one B/C group
+    Cm = jax.random.normal(ks[3], (B, S, 1, N))
     y16, s16 = ssd_chunked(x, a, Bm, Cm, 16)
     y64, s64 = ssd_chunked(x, a, Bm, Cm, 64)
     assert float(jnp.max(jnp.abs(y16 - y64))) < 1e-4

@@ -180,6 +180,31 @@ def test_decode_step_updates_the_stacked_cache_in_place(one_chip, cell):
     assert temp < math.prod(layer) * bf16 + weight, temp
 
 
+def test_zamba2_stage_is_served_in_place_within_one_chip(one_chip):
+    """The zamba2-7b-l21.decode cell's served programs (21 layers at
+    published widths, hybrid ids 6, 11, 17; batch 64, prompt 512, cache
+    1024): the prefill, its chunked scan's temporaries bounded
+    (``ssm.scan_rows``), and the decode step each fit one chip beside
+    their arguments and results, and the decode step writes the tied
+    blocks' 224-wide K/V rows in place, with no copy or slice of a
+    layer's cache."""
+    cfg = dataclasses.replace(get_config("zamba2-7b"), num_layers=21)
+    b, p, g = 64, 512, 512
+    opts = RunOptions(chunk_q=32, chunk_kv=32, cache_len=p + g,
+                      remat=False, decode_scan=True)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct((b, p), jnp.int32,
+                                            sharding=one_chip)}
+    prefill, step, _ = serve.compile_step_fns(cfg, params, batch, opts, p)
+    for program in (prefill, step):
+        used = _device_bytes(program.compiled)
+        assert 0 < used < HBM_BYTES, used
+    a = cfg.attention
+    layer = [b, a.num_kv_heads, a.head_dim, p + g]
+    assert _moves(_scheduled_ops(step.compiled.as_text()), [layer]) == []
+
+
 @pytest.mark.parametrize("m,k,n", [(256, 896, 4864), (512, 4864, 896)])
 def test_spm_matmul_compiles_for_v5e(one_chip, m, k, n):
     from repro.kernels.spm_matmul.ops import matmul
